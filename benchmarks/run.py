@@ -15,6 +15,11 @@ the driver, PR-1 routing) vs ``direct`` (peer-to-peer executor
 channels). The ``steadystate_speedup`` row states warm+direct against
 cold+relay -- the acceptance criterion is >= 5x.
 
+This is a CPU harness. Its entry sets ``JAX_PLATFORMS=cpu`` for itself
+and every process it starts, so it never takes a TPU chip and no row it
+prints is a chip number: the rows are host wall-clock on the CPU
+backend, Pallas in interpret mode.
+
 Output: ``name,us_per_call,derived`` CSV on stdout, and the same rows as
 machine-readable JSON with ``--json PATH`` (perf trajectory across PRs).
 Usage: PYTHONPATH=src python -m benchmarks.run [--quick] [--json PATH]
@@ -1109,6 +1114,8 @@ def main() -> None:
                     help="exit nonzero unless every required listing row "
                          "was produced and none failed (CI smoke gate)")
     args = ap.parse_args()
+    # before anything imports jax; spawned and forked children inherit it
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     bench_listing1_matvec()
     bench_listing2_ring()

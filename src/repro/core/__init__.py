@@ -11,9 +11,8 @@
 - ``comm``      : SPMD ``PeerComm`` over mesh axes (linear/ring/native)
 - ``closures``  : ``parallelize_func(f).execute(n)`` in local, cluster or
                   SPMD mode
-- ``compat``    : shims over jax version differences (shard_map, set_mesh)
 """
-from . import compat, groups
+from . import groups
 from .comm import PeerComm, cost_log, cost_scope
 from .closures import (MPIgniteContext, ParallelClosure, RANK_AXIS, flat_mesh,
                        parallelize_func)
@@ -25,7 +24,7 @@ from .matching import (Mailbox, MessageComm, PeerDeadError, ProgressEngine,
                        Request, waitall, waitany)
 
 __all__ = [
-    "groups", "compat", "PeerComm", "cost_log", "cost_scope",
+    "groups", "PeerComm", "cost_log", "cost_scope",
     "MPIgniteContext", "ParallelClosure",
     "RANK_AXIS", "flat_mesh", "parallelize_func", "LocalComm",
     "ParallelFuncRDD", "ClusterComm", "ClusterFuncRDD", "ClusterPool",

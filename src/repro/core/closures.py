@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import compat
 from .comm import PeerComm
 from .local import ParallelFuncRDD
 
@@ -108,10 +107,10 @@ class ParallelClosure:
                 out = jnp.zeros((), jnp.int32)
             return jax.tree.map(lambda v: jnp.asarray(v)[None], out)
 
-        smapped = compat.shard_map(body, mesh=mesh, in_specs=(),
+        smapped = jax.shard_map(body, mesh=mesh, in_specs=(),
                                 out_specs=P(RANK_AXIS))
         run = jax.jit(smapped) if jit else smapped
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = run()
         out = jax.tree.map(np.asarray, out)
         leaves = jax.tree.leaves(out)

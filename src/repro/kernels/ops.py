@@ -1,32 +1,17 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""Keyword-argument entry the models call for the Pallas flash kernel.
 
-On this CPU container the kernels execute in interpret mode (the Pallas
-body runs in Python for correctness validation); on TPU the same calls
-compile to Mosaic. ``INTERPRET`` flips automatically.
+The kernels compile for the TPU. Interpret mode (the Pallas body run in
+Python, for checking on a CPU) is never chosen here: a caller that wants
+it calls the kernel module with ``interpret=True``, as the kernel tests
+do. Importing this module starts no JAX backend.
 """
 from __future__ import annotations
 
-import jax
-
 from .flash_attention import flash_attention as _flash
-from .rmsnorm import rmsnorm as _rmsnorm
-from .ssd_scan import ssd_scan as _ssd
-
-INTERPRET = jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, block_q: int = 128,
                     block_k: int = 128):
-    return _flash(q, k, v, causal, window, q_offset, block_q, block_k,
-                  INTERPRET)
+    return _flash(q, k, v, causal, window, q_offset, block_q, block_k)
 
-
-def ssd_scan(x, dt, a_log, Bm, Cm, *, chunk: int = 128):
-    """Training hot path: y only (prefill, which also needs the final
-    state, uses the XLA chunk decomposition -- see models.ssm)."""
-    return _ssd(x, dt, a_log, Bm, Cm, chunk=chunk, interpret=INTERPRET)
-
-
-def rmsnorm(x, w, *, eps: float = 1e-5):
-    return _rmsnorm(x, w, eps=eps, interpret=INTERPRET)

@@ -17,7 +17,6 @@ from jax.sharding import NamedSharding  # noqa: E402
 
 from ..configs import SHAPES, get_config, skip_reason, cell_plan  # noqa: E402
 from ..core.comm import cost_log                                  # noqa: E402
-from ..core import compat                                         # noqa: E402
 from ..models.model import Model                                  # noqa: E402
 from ..parallel import axes as A                                  # noqa: E402
 from ..parallel.ops import ParallelConfig                         # noqa: E402
@@ -159,7 +158,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, path: str,
                                      lean_opt=lean_opt)
     t0 = time.time()
     with cost_log() as clog:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = lower_fn()
     t_lower = time.time() - t0
     t0 = time.time()

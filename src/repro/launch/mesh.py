@@ -7,11 +7,8 @@ import jax
 
 
 def _mk(shape, names):
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(names)
-        return jax.make_mesh(shape, names, axis_types=axis_types)
-    except (TypeError, AttributeError):  # older jax: no AxisType kwarg/enum
-        return jax.make_mesh(shape, names)
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

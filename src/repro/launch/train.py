@@ -21,7 +21,6 @@ import jax
 from jax.sharding import NamedSharding
 
 from ..configs import get_config
-from ..core import compat
 from ..data.pipeline import SyntheticTokens, make_batch
 from ..models.model import Model
 from ..parallel import axes as A
@@ -30,6 +29,7 @@ from ..train import checkpoint as CKPT
 from ..train import ft
 from ..train.optim import OptConfig, Optimizer
 from ..train.step import init_opt_state, make_train_step
+from .cache import use_compile_cache
 
 
 def build(cfg, mesh, pcfg, opt_cfg, global_batch):
@@ -66,6 +66,7 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     n_dev = args.data * args.model_par
     if n_dev > len(jax.devices()):
@@ -113,7 +114,7 @@ def main(argv=None):
                 for k, v in batch.items()}
             injector.check(step)
             t0 = time.time()
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 params, opt_state, metrics = step_fn(params, opt_state,
                                                      batch)
             dt = time.time() - t0

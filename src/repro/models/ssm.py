@@ -39,8 +39,8 @@ def ssd_chunked(x, dt, a_log, Bm, Cm, chunk: int, impl: str = "xla"):
     a_log: (H,) (A = -exp(a_log)), Bm/Cm: (B,S,N). Returns y: (B,S,H,P)
     and the final state (B,H,P,N)."""
     if impl == "pallas":
-        from ..kernels import ops as kops
-        y = kops.ssd_scan(x, dt, a_log, Bm, Cm, chunk=chunk)
+        from ..kernels.ssd_scan import ssd_scan
+        y = ssd_scan(x, dt, a_log, Bm, Cm, chunk=chunk)
         return y, None   # train path; prefill uses impl="xla" for the state
     B, S, H, Pd = x.shape
     N = Bm.shape[-1]

@@ -20,7 +20,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.common import tree_pspecs
 from ..models.model import Model
 from ..parallel import axes as A
-from ..core import compat
 from ..parallel.ops import ParallelConfig, ShardOps, make_ops
 from . import compress as C
 from .optim import Optimizer
@@ -136,7 +135,7 @@ def make_train_step(model: Model, opt: Optimizer, mesh: Mesh,
     metrics_ps = {"loss": P(), "gnorm": P(), "aux": P(), "step": P()}
 
     if pcfg.path == "mpignite":
-        smapped = compat.shard_map(
+        smapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(param_ps, opt_ps, batch_ps),
             out_specs=(param_ps, opt_ps, metrics_ps),
@@ -182,7 +181,7 @@ def make_prefill_step(model: Model, mesh: Mesh, global_batch: int,
     cache_ps = tree_pspecs(serve_model.cache_specs(global_batch, s_max))
     logits_ps = P(_first(batch_ps), None)
     if pcfg.path == "mpignite":
-        smapped = compat.shard_map(body, mesh=mesh,
+        smapped = jax.shard_map(body, mesh=mesh,
                                 in_specs=(param_ps, batch_ps),
                                 out_specs=(logits_ps, cache_ps),
                                 check_vma=False)
@@ -208,7 +207,7 @@ def make_decode_step(model: Model, mesh: Mesh, batch: int, s_max: int):
     pos_ps = P(bsp)
     logits_ps = P(bsp, None)
     if pcfg.path == "mpignite":
-        smapped = compat.shard_map(
+        smapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(param_ps, cache_ps, tok_ps, pos_ps),
             out_specs=(logits_ps, cache_ps), check_vma=False)

@@ -12,7 +12,6 @@ import numpy as np                                            # noqa: E402
 from jax.sharding import NamedSharding                        # noqa: E402
 
 from repro.core import parallelize_func                       # noqa: E402
-from repro.core import compat                                 # noqa: E402
 from repro.configs import get_config                          # noqa: E402
 from repro.models.model import Model                          # noqa: E402
 from repro.parallel import axes as A                          # noqa: E402
@@ -92,7 +91,7 @@ def check_train_step_on_mesh():
         batch = {"tokens": jax.device_put(
             tokens, NamedSharding(mesh, ps["batch"]["tokens"]))}
         ls, gn = [], []
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for _ in range(5):
                 params, state, metrics = step(params, state, batch)
                 ls.append(float(metrics["loss"]))
@@ -129,7 +128,7 @@ def check_decode_on_mesh():
     decode = make_decode_step(model, mesh, B, s_max=s_max)
     sh = lambda t, s: jax.device_put(t, NamedSharding(mesh, s))
     _, bps = model.batch_specs(B, S)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, caches = prefill(params, {"tokens": sh(
             jnp.asarray(tokens), bps["tokens"])})
         tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
@@ -214,7 +213,7 @@ def check_elastic_remesh_restart():
     batch = {"tokens": jax.device_put(tokens, NamedSharding(
         mesh, ps["batch"]["tokens"]))}
     losses = []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for _ in range(3):
             params, state, metrics = step(params, state, batch)
             losses.append(float(metrics["loss"]))
@@ -233,7 +232,7 @@ def check_elastic_remesh_restart():
                  if k.startswith("opt/")}, mesh2, ps2["opt"])
     batch2 = {"tokens": jax.device_put(tokens, NamedSharding(
         mesh2, ps2["batch"]["tokens"]))}
-    with compat.set_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         for _ in range(3):
             params2, state2, metrics2 = step2(params2, state2, batch2)
             losses.append(float(metrics2["loss"]))

@@ -1,5 +1,9 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles
 (interpret=True executes each Pallas body on CPU)."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -105,3 +109,14 @@ def test_rmsnorm_matches_oracle(shape, dtype):
     want = ref.rmsnorm_ref(x, w)
     np.testing.assert_allclose(out.astype(np.float32),
                                want.astype(np.float32), atol=2e-2, rtol=2e-2)
+
+
+def test_kernel_ops_import_starts_no_backend():
+    """Interpret mode is the caller's choice, so importing the kernels
+    probes no device (a process may import them before it picks one)."""
+    code = ("import repro.kernels.ops, repro.kernels.rmsnorm\n"
+            "import repro.kernels.ssd_scan\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu"})

@@ -12,7 +12,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core import compat
 from repro.core.comm import PeerComm
 from repro.parallel.pipeline import gpipe, stack_stages
 
@@ -52,10 +51,10 @@ def run(staged, xs):
     # outputs live on the last stage; broadcast makes them replicated
     return comm.broadcast(out, root=S - 1)
 
-piped = jax.jit(compat.shard_map(
+piped = jax.jit(jax.shard_map(
     run, mesh=mesh, in_specs=(P("pipe"), P()), out_specs=P(),
     check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = piped(staged, xs)
 np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                            atol=1e-5, rtol=1e-5)
@@ -69,7 +68,7 @@ def loss_pipe(staged, xs):
     # true loss because only the last stage banks non-zero outputs.
     return jnp.sum(out ** 2)
 
-gfn = jax.jit(compat.shard_map(
+gfn = jax.jit(jax.shard_map(
     jax.grad(loss_pipe), mesh=mesh, in_specs=(P("pipe"), P()),
     out_specs=P("pipe"), check_vma=False))
 
@@ -77,7 +76,7 @@ def loss_ref(Ws):
     return jnp.sum(ref_forward(Ws, xs) ** 2)
 
 gref = jax.grad(loss_ref)(Ws)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     gpiped = gfn(staged, xs)
 np.testing.assert_allclose(np.asarray(gpiped).reshape(L, D, D),
                            np.asarray(gref), atol=1e-4, rtol=1e-4)

@@ -3,12 +3,19 @@ must equal direct prefill+decode on the same model; slots recycle;
 termination (EOS / budget / context cap) is honored at prefill and at
 decode; speculative decoding is bit-identical to plain greedy."""
 import dataclasses
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import CHECKOUT_CACHE
+from repro.launch.serve import (LOGIT_GAP_BOUND, LogitWatch, build_engine,
+                                decode_prefill_gap)
 from repro.models.common import ParamSpec
 from repro.models.model import Model
 from repro.parallel import axes as A
@@ -348,3 +355,52 @@ def test_cluster_server_local_mode_routes_and_drains():
     prefills = [srv.replica_stats[s]["stats"]["prefills"]
                 for s in sorted(srv.replica_stats)]
     assert sum(prefills) == 7 and all(p > 0 for p in prefills)
+
+
+# ---------------------------------------------------------------------------
+# The serving build that launch.serve and chip_smoke.py share, with the
+# cache-consistency check chip_smoke.py runs on the chip
+# ---------------------------------------------------------------------------
+
+def test_build_engine_serves_and_first_decode_matches_prefill():
+    cfg = get_config("h2o-danube-1.8b", smoke=True)   # bfloat16, as served
+    eng = build_engine(cfg, max_slots=4, s_max=64, seed=0)
+    assert all(x.dtype == cfg.dtype for x in jax.tree.leaves(eng.params)
+               if x.ndim > 1)
+    watch = LogitWatch(eng)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32)
+               for n in (8, 16, 8, 16)]
+    uids = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    out = eng.run()
+    assert [len(out[u]) for u in uids] == [5] * 4
+    assert watch.all_finite()
+    for slot, (uid, p) in enumerate(zip(uids, prompts)):
+        gap = decode_prefill_gap(eng, watch, slot, p, out[uid][0])
+        assert gap <= LOGIT_GAP_BOUND, (slot, gap)
+
+
+def test_compile_cache_dir_from_env_or_checkout(tmp_path):
+    where = ("from repro.launch.cache import use_compile_cache\n"
+             "print(use_compile_cache())\n")
+    compile_one = ("import jax, jax.numpy as jnp\n"
+                   "jax.config.update("
+                   "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+                   "jax.jit(lambda x: x * 3 + 1)(jnp.ones(4))"
+                   ".block_until_ready()\n")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+
+    def run(code):
+        return subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120,
+                              check=True).stdout.strip()
+
+    # unset: a fixed path at the root of the checkout
+    assert run(where) == str(CHECKOUT_CACHE)
+    assert CHECKOUT_CACHE == Path(__file__).resolve().parents[1] / ".jax_cache"
+    # set: JAX's own variable wins, and the compile lands there
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    assert run(where + compile_one) == str(tmp_path / "cache")
+    assert any((tmp_path / "cache").iterdir())
