@@ -14,6 +14,7 @@ from .trace import (
     JobTrace,
     Tracer,
     current_span,
+    maybe_span,
     process_tracer,
     reset_process_tracer,
     set_current_span,
@@ -25,6 +26,6 @@ __all__ = [
     "ChannelStats", "cross_check_collectives", "format_cross_check",
     "DEFAULT_CAPACITY", "TRACE_ENV", "TRACE_EVENTS_ENV",
     "CollSpan", "JobTrace", "Tracer",
-    "current_span", "set_current_span",
+    "current_span", "maybe_span", "set_current_span",
     "process_tracer", "reset_process_tracer", "trace_enabled",
 ]

@@ -27,6 +27,13 @@ Design constraints, in order:
    under a scheduling quantum. Multi-host merges inherit NTP skew --
    documented, not hidden.
 
+Scoped host work outside the message runtime (the serving engine's
+admission and decode steps) uses ``Tracer.span``: one context manager
+that records the span into the ring *and* enters a
+``jax.profiler.TraceAnnotation``, so the same span also lands on the
+profiler's clock beside the device's operations. ``maybe_span`` is its
+guard for instrumentation points whose tracer may be None.
+
 Event tuples are ``(ph, cat, name, ts_ns, dur_ns, tid, args)`` where
 ``ph`` is the Chrome trace phase (``"X"`` complete span, ``"i"``
 instant, ``"C"`` counter), ``ts_ns`` is raw ``perf_counter_ns``, and
@@ -41,6 +48,7 @@ collective > schedule step > segment -- renders correctly.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -169,7 +177,6 @@ class Tracer:
         self._n = 0                 # live events (<= capacity)
         self.dropped = 0
         self._lock = threading.Lock()
-        self._open: dict[int, list] = {}    # thread id -> begin stack
         self._track_seq = 0
         #: wall-clock anchor: add to any perf_counter_ns timestamp from
         #: this process to land on the (shared) wall clock.
@@ -212,26 +219,18 @@ class Tracer:
         self._record(("C", cat, name, time.perf_counter_ns(), 0, "counters",
                       {"value": value}))
 
-    # -- begin/end (balanced-span API; per-thread stack) --------------------
-    def begin(self, name: str, cat: str = "", args: dict | None = None
-              ) -> None:
-        """Open a span on this thread's stack; ``end()`` closes the most
-        recent one and records the X event. Strictly LIFO per thread."""
-        stack = self._open.setdefault(threading.get_ident(), [])
-        stack.append((name, cat, time.perf_counter_ns(), args))
-
-    def end(self) -> None:
-        stack = self._open.get(threading.get_ident())
-        if not stack:
-            raise RuntimeError("Tracer.end() with no open span on this "
-                               "thread (begin/end imbalance)")
-        name, cat, t0, args = stack.pop()
-        self.complete(name, cat, t0, args=args)
-
-    def open_spans(self) -> int:
-        """How many begin()s have no matching end() yet, across all
-        threads -- 0 after balanced instrumentation."""
-        return sum(len(s) for s in self._open.values())
+    # -- scoped spans (both clocks) -----------------------------------------
+    def span(self, name: str, cat: str = "", args: dict | None = None
+             ) -> "Span":
+        """Context manager: a complete span around the body on this
+        thread's track, recorded when the body ends (also when it
+        raises, with the exception's type under ``args["error"]``).
+        The body also runs inside ``jax.profiler.TraceAnnotation(name,
+        **args)``, so the span lands on the host plane of any running
+        profiler trace -- the clock the device's operations are on.
+        Spans nest by thread; a span's parent is the innermost one
+        open around it."""
+        return Span(self, name, cat, args)
 
     # -- collective spans ---------------------------------------------------
     def coll_begin(self, op: str, backend: str, p: int, nbytes: int,
@@ -271,6 +270,49 @@ class Tracer:
                 "wall_minus_perf": self.wall_minus_perf,
                 "dropped": self.dropped, "events": self.events(),
                 "counters": dict(self.counters)}
+
+
+class Span:
+    """One open ``Tracer.span``: the ring record and the profiler
+    annotation around the same body."""
+    __slots__ = ("tracer", "name", "cat", "args", "t0", "ann")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str,
+                 args: dict | None):
+        self.tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.args = args
+
+    def __enter__(self) -> "Span":
+        # imported here, so that processes which never open a span (the
+        # message runtime's executors) never import JAX for it
+        from jax.profiler import TraceAnnotation
+        self.ann = (TraceAnnotation(self.name, **self.args) if self.args
+                    else TraceAnnotation(self.name))
+        self.ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = time.perf_counter_ns()
+        self.ann.__exit__(exc_type, exc, tb)
+        args = self.args
+        if exc_type is not None:
+            args = dict(args or {}, error=exc_type.__name__)
+        self.tracer.complete(self.name, self.cat, self.t0, t1, args)
+
+
+#: what ``maybe_span`` hands back with tracing off: one shared, stateless
+#: context, so a disabled instrumentation point allocates nothing
+_NO_SPAN = contextlib.nullcontext()
+
+
+def maybe_span(tracer: Tracer | None, name: str, cat: str = "",
+               args: dict | None = None):
+    """``tracer.span(name, cat, args)``, or a shared do-nothing context
+    when ``tracer`` is None (build ``args`` only when it is not)."""
+    return _NO_SPAN if tracer is None else tracer.span(name, cat, args)
 
 
 # ---------------------------------------------------------------------------

@@ -26,7 +26,7 @@ from ..models.common import ModelConfig
 from ..models.model import Model
 from ..parallel import axes as A
 from ..parallel.ops import ParallelConfig, make_ops
-from ..serve.engine import Engine
+from ..serve.engine import ENV_TRACER, Engine
 from .cache import use_compile_cache
 
 #: Bound on the cache-consistency gap (``decode_prefill_gap``): the
@@ -65,13 +65,14 @@ def serving_steps(model: Model, s_max: int):
 
 
 def build_engine(cfg: ModelConfig, *, max_slots: int, s_max: int,
-                 seed: int = 0) -> Engine:
-    """Model, seeded parameters in ``cfg.dtype``, compiled steps, engine."""
+                 seed: int = 0, tracer=ENV_TRACER) -> Engine:
+    """Model, seeded parameters in ``cfg.dtype``, compiled steps, engine.
+    ``tracer`` is the engine's (``Engine``'s default: ``$MPIGNITE_TRACE``)."""
     model = serving_model(cfg)
     params = model.init(jax.random.PRNGKey(seed))
     prefill_fn, decode_fn = serving_steps(model, s_max)
     return Engine(model, params, prefill_fn, decode_fn,
-                  max_slots=max_slots, s_max=s_max)
+                  max_slots=max_slots, s_max=s_max, tracer=tracer)
 
 
 class LogitWatch:

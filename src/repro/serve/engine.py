@@ -21,6 +21,17 @@ can tell a context-capped generation from a naturally finished one.
 Finishing can happen *at prefill* (first token is EOS, or the budget is
 one): such a request never occupies a slot and is returned by the next
 ``step()``/``run()``.
+
+Tracing: with a tracer (``Engine(tracer=...)``, or ``$MPIGNITE_TRACE``
+through the process tracer) each step records ``serve.*`` spans into
+the tracer's ring and onto the host plane of any running
+``jax.profiler`` trace: ``serve.step`` around the step; ``serve.admit``
+(one admission) holding ``serve.prefill`` and ``serve.splice`` (with a
+draft model, the draft's prefill and splice too); ``serve.decode``
+(input build and dispatch), ``serve.fetch`` (the host's wait for the
+next tokens) and ``serve.emit`` (termination bookkeeping); and, in the
+ring only, ``serve.queue`` from ``submit()`` to the start of the
+request's admission. Without a tracer each point is an ``is None`` test.
 """
 from __future__ import annotations
 
@@ -33,6 +44,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.obs.metrics import AcceptanceStats
+from ..core.obs.trace import maybe_span, process_tracer
+
+#: category of the engine's spans in the tracer's ring
+SPAN_CAT = "serve"
+#: ``Engine(tracer=...)`` default: the process tracer, which
+#: ``$MPIGNITE_TRACE`` turns on
+ENV_TRACER = object()
 
 #: bounded debugging window of recent per-step occupancies kept by
 #: EngineStats (the running sum/count is what long-lived replicas use)
@@ -51,6 +69,9 @@ class Request:
     #: EOS and not ``max_new_tokens`` -- the caller's signal that the
     #: generation was cut off rather than completed
     truncated: bool = False
+    #: ``perf_counter_ns`` at ``submit()`` when the engine traces (0 when
+    #: it does not): the start of the request's ``serve.queue`` span
+    queued_ns: int = 0
 
 
 class Generation(list):
@@ -122,11 +143,16 @@ class Engine:
     ``batch_axes`` optionally pins the cache batch axis (one int for
     every leaf, or a pytree of ints congruent with the cache); when
     omitted the engine derives each leaf's batch axis from the model's
-    ``cache_specs`` metadata -- see ``_batch_axis_tree``."""
+    ``cache_specs`` metadata -- see ``_batch_axis_tree``.
+
+    ``tracer`` is an ``obs.Tracer`` that records the engine's spans (see
+    the module docstring), or None for none; the default is the process
+    tracer, on when ``$MPIGNITE_TRACE`` asks. ``self.tracer`` is read at
+    every call, so it may also be set on a built engine."""
 
     def __init__(self, model, params, prefill_fn: Callable,
                  decode_fn: Callable, max_slots: int, s_max: int,
-                 spec=None, batch_axes=None):
+                 spec=None, batch_axes=None, tracer=ENV_TRACER):
         self.model = model
         self.params = params
         self.prefill_fn = prefill_fn
@@ -143,6 +169,7 @@ class Engine:
         self.acceptance = AcceptanceStats()
         self.spec = spec
         self._batch_axes = batch_axes
+        self.tracer = process_tracer() if tracer is ENV_TRACER else tracer
         self._axis_tree = None                  # resolved on first prefill
         self._draft_caches = None
         self._draft_axis_tree = None
@@ -163,8 +190,10 @@ class Engine:
             uid = self._uid
         else:
             self._uid = max(self._uid, int(uid))
+        queued_ns = 0 if self.tracer is None else self.tracer.now()
         self.queue.append(Request(uid, np.asarray(prompt, np.int32),
-                                  max_new_tokens, eos_id))
+                                  max_new_tokens, eos_id,
+                                  queued_ns=queued_ns))
         return uid
 
     def pending(self) -> int:
@@ -189,33 +218,39 @@ class Engine:
 
     # ---- engine step --------------------------------------------------------
     def step(self) -> list[Request]:
-        self._admit()
-        finished: list[Request] = list(self._prefill_finished)
-        self._prefill_finished.clear()
-        if not any(self.active):
+        tr = self.tracer
+        with maybe_span(tr, "serve.step", SPAN_CAT):
+            self._admit(tr)
+            finished: list[Request] = list(self._prefill_finished)
+            self._prefill_finished.clear()
+            if not any(self.active):
+                return finished
+            if self.spec is not None and self._spec_eligible():
+                return finished + self._spec_step(tr)
+            with maybe_span(tr, "serve.decode", SPAN_CAT):
+                tokens = jnp.asarray(self.cur_tok)[:, None]
+                pos = jnp.asarray(self.pos)
+                logits, self.caches = self.decode_fn(self.params, self.caches,
+                                                     tokens, pos)
+                if self._draft_caches is not None:
+                    # keep the draft cache position-consistent: the draft
+                    # decodes the same token at the same position the
+                    # target just did, so a later spec round resumes from
+                    # an aligned prefix
+                    _, self._draft_caches = self.spec.draft_decode(
+                        self._draft_caches, tokens, pos)
+            self.stats.decode_steps += 1
+            self.stats.record_occupancy(int(self.active.sum()))
+            with maybe_span(tr, "serve.fetch", SPAN_CAT):
+                next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            with maybe_span(tr, "serve.emit", SPAN_CAT):
+                for i, req in enumerate(self.slots):
+                    if req is None or not self.active[i]:
+                        continue
+                    self.pos[i] += 1
+                    if self._emit(i, req, int(next_tok[i])):
+                        finished.append(req)
             return finished
-        if self.spec is not None and self._spec_eligible():
-            return finished + self._spec_step()
-        tokens = jnp.asarray(self.cur_tok)[:, None]
-        pos = jnp.asarray(self.pos)
-        logits, self.caches = self.decode_fn(self.params, self.caches,
-                                             tokens, pos)
-        if self._draft_caches is not None:
-            # keep the draft cache position-consistent: the draft decodes
-            # the same token at the same position the target just did, so
-            # a later spec round resumes from an aligned prefix
-            _, self._draft_caches = self.spec.draft_decode(
-                self._draft_caches, tokens, pos)
-        self.stats.decode_steps += 1
-        self.stats.record_occupancy(int(self.active.sum()))
-        next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        for i, req in enumerate(self.slots):
-            if req is None or not self.active[i]:
-                continue
-            self.pos[i] += 1
-            if self._emit(i, req, int(next_tok[i])):
-                finished.append(req)
-        return finished
 
     def _emit(self, slot: int, req: Request, tok: int) -> bool:
         """Append one generated token; apply the termination contract.
@@ -248,7 +283,7 @@ class Engine:
         act = self.active
         return bool(np.all(self.pos[act] + gamma < self.s_max))
 
-    def _spec_step(self) -> list[Request]:
+    def _spec_step(self, tr) -> list[Request]:
         """One speculative round: the draft proposes gamma tokens per
         slot, the target verifies them in one fused dispatch, and each
         slot emits its accepted prefix plus the target's correction
@@ -256,54 +291,67 @@ class Engine:
         to plain decoding."""
         sp = self.spec
         gamma = sp.gamma
-        # inactive rows still flow through the batched scans; pin their
-        # inputs to position 0 so the dead rows' writes never clamp
-        pos_in = np.where(self.active, self.pos, 0).astype(np.int32)
-        tok_in = np.where(self.active, self.cur_tok, 0).astype(np.int32)
-        draft_toks, self._draft_caches = sp.propose(
-            self._draft_caches, jnp.asarray(tok_in), jnp.asarray(pos_in))
-        verified, self.caches = sp.verify(
-            self.params, self.caches, jnp.asarray(tok_in), draft_toks,
-            jnp.asarray(pos_in))
+        with maybe_span(tr, "serve.decode", SPAN_CAT):
+            # inactive rows still flow through the batched scans; pin
+            # their inputs to position 0 so the dead rows' writes never
+            # clamp
+            pos_in = np.where(self.active, self.pos, 0).astype(np.int32)
+            tok_in = np.where(self.active, self.cur_tok, 0).astype(np.int32)
+            draft_toks, self._draft_caches = sp.propose(
+                self._draft_caches, jnp.asarray(tok_in), jnp.asarray(pos_in))
+            verified, self.caches = sp.verify(
+                self.params, self.caches, jnp.asarray(tok_in), draft_toks,
+                jnp.asarray(pos_in))
         self.stats.decode_steps += 1
         self.stats.spec_rounds += 1
         self.stats.record_occupancy(int(self.active.sum()))
-        d = np.asarray(draft_toks)              # (B, gamma)
-        v = np.asarray(verified)                # (B, gamma+1)
+        with maybe_span(tr, "serve.fetch", SPAN_CAT):
+            d = np.asarray(draft_toks)              # (B, gamma)
+            v = np.asarray(verified)                # (B, gamma+1)
         finished: list[Request] = []
-        for i, req in enumerate(self.slots):
-            if req is None or not self.active[i]:
-                continue
-            # longest prefix where the draft guessed the target's token
-            agree = d[i] == v[i, :gamma]
-            n_acc = int(np.cumprod(agree).sum())
-            self.acceptance.record(req.uid, gamma, n_acc)
-            for tok in v[i, :n_acc + 1]:
-                self.pos[i] += 1
-                if self._emit(i, req, int(tok)):
-                    finished.append(req)
-                    break
+        with maybe_span(tr, "serve.emit", SPAN_CAT):
+            for i, req in enumerate(self.slots):
+                if req is None or not self.active[i]:
+                    continue
+                # longest prefix where the draft guessed the target's token
+                agree = d[i] == v[i, :gamma]
+                n_acc = int(np.cumprod(agree).sum())
+                self.acceptance.record(req.uid, gamma, n_acc)
+                for tok in v[i, :n_acc + 1]:
+                    self.pos[i] += 1
+                    if self._emit(i, req, int(tok)):
+                        finished.append(req)
+                        break
         return finished
 
     # ---- admission + prefill -------------------------------------------------
-    def _admit(self):
+    def _admit(self, tr):
         for i in range(self.max_slots):
             # a request that finishes at prefill never takes the slot --
             # keep admitting into it until something survives prefill
             while self.slots[i] is None and self.queue:
                 req = self.queue.popleft()
-                self._prefill_into(i, req)
+                if tr is None:
+                    self._prefill_into(i, req, None)
+                    continue
+                if req.queued_ns:       # 0: queued while untraced
+                    tr.complete("serve.queue", SPAN_CAT, req.queued_ns,
+                                args={"uid": req.uid})
+                with tr.span("serve.admit", SPAN_CAT,
+                             {"uid": req.uid, "prompt_len": len(req.prompt)}):
+                    self._prefill_into(i, req, tr)
 
-    def _prefill_into(self, slot: int, req: Request):
+    def _prefill_into(self, slot: int, req: Request, tr):
         """Prefill one request and splice its cache into the batch cache.
         If the prefill token itself is terminal (EOS, a budget of one,
         or a prompt already at the cache limit), the request finishes
         here: it never occupies the slot, never costs a decode step, and
         is returned by the next ``step()``."""
-        batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
-        logits, cache1 = self.prefill_fn(self.params, batch)
-        self.stats.prefills += 1
-        first = int(np.argmax(np.asarray(logits)[0]))
+        with maybe_span(tr, "serve.prefill", SPAN_CAT):
+            batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
+            logits, cache1 = self.prefill_fn(self.params, batch)
+            self.stats.prefills += 1
+            first = int(np.argmax(np.asarray(logits)[0]))
         req.out_tokens.append(first)
         self.stats.tokens_out += 1
         pos = len(req.prompt)
@@ -318,16 +366,17 @@ class Engine:
             self.stats.prefill_finishes += 1
             self._prefill_finished.append(req)
             return
-        if self._axis_tree is None:
-            self._axis_tree = self._batch_axis_tree(cache1, self.model)
-        if self.caches is None:
+        with maybe_span(tr, "serve.splice", SPAN_CAT):
+            if self._axis_tree is None:
+                self._axis_tree = self._batch_axis_tree(cache1, self.model)
+            if self.caches is None:
+                self.caches = jax.tree_util.tree_map(
+                    self._widen, cache1, self._axis_tree)
             self.caches = jax.tree_util.tree_map(
-                self._widen, cache1, self._axis_tree)
-        self.caches = jax.tree_util.tree_map(
-            lambda full, one, ax: self._splice(full, one, slot, ax),
-            self.caches, cache1, self._axis_tree)
-        if self.spec is not None:
-            self._prefill_draft(slot, req)
+                lambda full, one, ax: self._splice(full, one, slot, ax),
+                self.caches, cache1, self._axis_tree)
+            if self.spec is not None:
+                self._prefill_draft(slot, req)
         self.slots[slot] = req
         self.active[slot] = True
         self.pos[slot] = pos

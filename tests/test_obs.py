@@ -1,5 +1,5 @@
-"""Observability plane: the per-rank tracer (ring buffer, span balance,
-zero-cost disabled path), driver-side aggregation across real executor
+"""Observability plane: the per-rank tracer (ring buffer, scoped spans
+on the ring and the profiler's clock, zero-cost disabled path), driver-side aggregation across real executor
 processes, Perfetto/Chrome export, the measured-vs-analytic byte
 cross-check, always-on runtime health counters, rank-tagged logging, and
 heartbeat-RTT rank health."""
@@ -50,18 +50,65 @@ def test_ring_buffer_wraps_oldest_first():
     assert ts == sorted(ts)
 
 
-def test_begin_end_balance_and_imbalance():
+def test_span_nesting_args_and_error():
     tr = Tracer(0, 1, capacity=64)
-    tr.begin("outer", "t")
-    tr.begin("inner", "t")
-    assert tr.open_spans() == 2
-    tr.end()
-    tr.end()
-    assert tr.open_spans() == 0
-    names = [e[2] for e in tr.events()]
-    assert names == ["inner", "outer"]      # LIFO close order
-    with pytest.raises(RuntimeError, match="imbalance"):
-        tr.end()
+    with tr.span("outer", "t"):
+        with tr.span("inner", "t", {"uid": 7}) as sp:
+            assert sp.name == "inner"
+    with pytest.raises(ValueError):
+        with tr.span("fails", "t", {"uid": 8}):
+            raise ValueError("boom")
+    (inner, outer, fails) = tr.events()
+    # recorded as each body ends: the inner span first, inside the outer
+    assert [e[2] for e in (inner, outer)] == ["inner", "outer"]
+    assert outer[3] <= inner[3]
+    assert inner[3] + inner[4] <= outer[3] + outer[4]
+    assert inner[0] == outer[0] == "X" and inner[1] == "t"
+    assert inner[6] == {"uid": 7} and outer[6] is None
+    # a body that raises still records its span, and says what it raised
+    assert fails[6] == {"uid": 8, "error": "ValueError"}
+    assert inner[5] == outer[5] == threading.current_thread().name
+
+
+PROFILED_SPAN = r'''
+import glob, json, sys
+import jax, jax.numpy as jnp
+from jax.profiler import ProfileData
+from repro.core.obs import Tracer
+
+tr = Tracer(0, 1, capacity=16)
+jax.profiler.start_trace(sys.argv[1])
+try:
+    with tr.span("serve.admit", "serve", {"uid": 3, "prompt_len": 9}):
+        jnp.ones(8).block_until_ready()
+finally:
+    jax.profiler.stop_trace()
+(path,) = glob.glob(sys.argv[1] + "/plugins/profile/*/*.xplane.pb")
+found = [{"plane": plane.name, "dur": e.duration_ns,
+          "stats": {k: v for k, v in e.stats}}
+         for plane in ProfileData.from_file(path).planes
+         for line in plane.lines for e in line.events
+         if e.name == "serve.admit"]
+print(json.dumps({"found": found, "ring": [e[2] for e in tr.events()]}))
+'''
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """The same span, read back from a ``jax.profiler`` trace on the CPU:
+    named as in the ring, its args as the event's stats, on the host
+    plane whose clock the device's operations share. (Its own process:
+    this one forks executors, which must not follow a JAX start-up.)"""
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", PROFILED_SPAN, str(tmp_path)],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    (ev,) = out["found"]
+    assert ev["plane"].startswith("/host:") and ev["dur"] > 0
+    assert ev["stats"] == {"uid": 3, "prompt_len": 9}
+    assert out["ring"] == ["serve.admit"]
 
 
 def test_coll_span_accumulates_and_exports():

@@ -1,18 +1,24 @@
 """Continuous-batching engine: greedy generations through the slot engine
 must equal direct prefill+decode on the same model; slots recycle;
 termination (EOS / budget / context cap) is honored at prefill and at
-decode; speculative decoding is bit-identical to plain greedy."""
+decode; speculative decoding is bit-identical to plain greedy; the
+engine's spans match its counters and change no output, and with tracing
+off record and allocate nothing."""
 import dataclasses
 import os
 import subprocess
 import sys
+import tracemalloc
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
+from repro.core.obs import Tracer
+from repro.core.obs import trace as trace_mod
 from repro.launch.cache import CHECKOUT_CACHE
 from repro.launch.serve import (LOGIT_GAP_BOUND, LogitWatch, build_engine,
                                 decode_prefill_gap)
@@ -28,7 +34,8 @@ AXES1 = A.MeshAxes(1, 1, 1)
 PCFG = ParallelConfig(path="mpignite", sequence_parallel=False, remat="none")
 
 
-def build(arch="qwen3-4b", s_max=48, slots=3, gamma=0, draft="self"):
+def build(arch="qwen3-4b", s_max=48, slots=3, gamma=0, draft="self",
+          tracer=None):
     cfg = dataclasses.replace(get_config(arch, smoke=True),
                               dtype=jnp.float32)
     model = Model(cfg, AXES1, PCFG)
@@ -55,7 +62,7 @@ def build(arch="qwen3-4b", s_max=48, slots=3, gamma=0, draft="self"):
         spec = SpecDecoder(model, ops, dmodel, dparams, s_max=s_max,
                            gamma=gamma)
     eng = Engine(model, params, prefill_fn, decode_fn, max_slots=slots,
-                 s_max=s_max, spec=spec)
+                 s_max=s_max, spec=spec, tracer=tracer)
     return cfg, model, params, ops, eng
 
 
@@ -325,6 +332,140 @@ def test_spec_decode_falls_back_near_context_budget():
     assert out[uid].truncated and len(out[uid]) == 11
     assert eng.stats.spec_rounds > 0                 # spec ran early on
     assert eng.stats.decode_steps > eng.stats.spec_rounds   # then fell back
+
+
+# ---------------------------------------------------------------------------
+# Engine spans: counted like EngineStats, free when off
+# ---------------------------------------------------------------------------
+
+def _spans(tr: Tracer, name: str) -> list:
+    return [e for e in tr.events() if e[2] == name]
+
+
+@pytest.mark.parametrize("gamma", [0, 3], ids=["plain", "spec"])
+def test_traced_engine_outputs_bit_identical(gamma):
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, 256, n).astype(np.int32) for n in (5, 9, 7, 6)]
+    outs, stats = [], []
+    for tracer in (None, Tracer(0, 1, capacity=4096)):
+        cfg, model, params, ops, eng = build(gamma=gamma, draft="small",
+                                             tracer=tracer)
+        uids = [eng.submit(p, max_new_tokens=7) for p in prompts]
+        out = eng.run()
+        outs.append([list(out[u]) for u in uids])
+        stats.append(eng.stats.summary())
+    assert outs[0] == outs[1]
+    assert stats[0] == stats[1]
+    assert tracer.dropped == 0
+    assert len(_spans(tracer, "serve.decode")) == eng.stats.decode_steps
+    assert len(_spans(tracer, "serve.fetch")) == eng.stats.decode_steps
+    assert len(_spans(tracer, "serve.prefill")) == eng.stats.prefills
+    assert eng.stats.spec_rounds == (eng.stats.decode_steps if gamma else 0)
+
+
+def test_span_counts_match_engine_stats():
+    s_max = 32
+    pf, df = toy_fns(s_max)
+    tr = Tracer(0, 1, capacity=4096)
+    eng = Engine(ToyModel(s_max), None, pf, df, max_slots=2, s_max=s_max,
+                 tracer=tr)
+    rng = np.random.default_rng(9)
+    # budgets of one finish at prefill: admitted, never spliced
+    uids = [eng.submit(rng.integers(0, TOY_VOCAB, 3 + i % 4).astype(np.int32),
+                       max_new_tokens=1 if i % 3 == 0 else 4 + i % 5)
+            for i in range(9)]
+    steps = 0
+    while eng.queue or eng.active.any() or eng._prefill_finished:
+        eng.step()
+        steps += 1
+    st = eng.stats
+    assert tr.dropped == 0 and st.prefill_finishes == 3
+    assert len(_spans(tr, "serve.step")) == steps
+    assert len(_spans(tr, "serve.decode")) == st.decode_steps
+    assert len(_spans(tr, "serve.fetch")) == st.decode_steps
+    assert len(_spans(tr, "serve.emit")) == st.decode_steps
+    assert len(_spans(tr, "serve.prefill")) == st.prefills == 9
+    assert len(_spans(tr, "serve.splice")) == st.prefills - 3
+    queue = {e[6]["uid"]: e for e in _spans(tr, "serve.queue")}
+    admit = {e[6]["uid"]: e for e in _spans(tr, "serve.admit")}
+    assert sorted(queue) == sorted(admit) == uids
+    assert len(_spans(tr, "serve.queue")) == len(_spans(tr, "serve.admit"))
+    step_iv = [(e[3], e[3] + e[4]) for e in _spans(tr, "serve.step")]
+    admit_iv = [(e[3], e[3] + e[4]) for e in admit.values()]
+    for uid, a in admit.items():
+        q = queue[uid]
+        # the queue span ends where its admission starts, in a step
+        assert q[3] + q[4] <= a[3]
+        assert a[6]["prompt_len"] > 0
+        assert any(s0 <= a[3] and a[3] + a[4] <= s1 for s0, s1 in step_iv)
+    for e in _spans(tr, "serve.prefill") + _spans(tr, "serve.splice"):
+        assert any(s0 <= e[3] and e[3] + e[4] <= s1 for s0, s1 in admit_iv)
+        assert e[1] == "serve"
+
+
+def test_engine_tracer_defaults_to_the_environment(monkeypatch):
+    pf, df = toy_fns(16)
+    monkeypatch.delenv(trace_mod.TRACE_ENV, raising=False)
+    trace_mod.reset_process_tracer()
+    try:
+        assert Engine(ToyModel(16), None, pf, df, 2, 16).tracer is None
+        monkeypatch.setenv(trace_mod.TRACE_ENV, "1")
+        trace_mod.reset_process_tracer()
+        eng = Engine(ToyModel(16), None, pf, df, 2, 16)
+        assert eng.tracer is trace_mod.process_tracer() is not None
+        eng.submit(np.arange(3, dtype=np.int32), max_new_tokens=2)
+        eng.run()
+        assert len(_spans(eng.tracer, "serve.admit")) == 1
+    finally:
+        trace_mod.reset_process_tracer()
+
+
+def test_untraced_engine_step_allocates_nothing_in_trace_module():
+    """Tracing off, over a whole run: the trace module's code does no
+    work beyond the ``maybe_span`` guard (it enters no other function of
+    its own and calls nothing, so it constructs nothing), and holds no
+    allocation afterwards (the tracemalloc pin of the message runtime)."""
+    s_max = 32
+    pf, df = toy_fns(s_max)
+    eng = Engine(ToyModel(s_max), None, pf, df, max_slots=2, s_max=s_max,
+                 tracer=None)
+    rng = np.random.default_rng(10)
+
+    def load():
+        for i in range(6):
+            eng.submit(rng.integers(0, TOY_VOCAB, 4).astype(np.int32),
+                       max_new_tokens=1 if i == 0 else 5)
+        eng.run()
+
+    load()                                      # compile, warm code paths
+    here = trace_mod.__file__
+    entered, called = set(), []
+
+    def audit(frame, event, arg):
+        if event == "call" and frame.f_code.co_filename == here:
+            entered.add(frame.f_code.co_name)
+        caller = frame.f_back if event == "call" else frame
+        if (event in ("call", "c_call") and caller is not None
+                and caller.f_code.co_filename == here):
+            called.append(arg if event == "c_call" else frame.f_code)
+
+    steps0 = eng.stats.decode_steps
+    sys.setprofile(audit)
+    try:
+        load()
+    finally:
+        sys.setprofile(None)
+    assert eng.stats.decode_steps > steps0
+    assert entered == {"maybe_span"} and not called, (entered, called)
+    tracemalloc.start()
+    try:
+        load()
+        snap = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    stats = snap.filter_traces(
+        [tracemalloc.Filter(True, here)]).statistics("lineno")
+    assert not stats, [str(s) for s in stats]
 
 
 # ---------------------------------------------------------------------------
