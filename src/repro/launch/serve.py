@@ -52,7 +52,9 @@ def serving_model(cfg: ModelConfig) -> Model:
 
 
 def serving_steps(model: Model, s_max: int):
-    """The jitted (prefill, decode) programs the engine runs."""
+    """The jitted (prefill, decode) programs the engine runs. Decode
+    donates its cache argument: the cache it returns is written into the
+    same buffers, so a caller must not read a cache it passed to decode."""
     ops = make_ops(model.axes, model.pcfg)
 
     def prefill(params, batch):
@@ -61,7 +63,7 @@ def serving_steps(model: Model, s_max: int):
     def decode(params, caches, tokens, pos):
         return model.decode(ops, params, caches, tokens, pos)
 
-    return jax.jit(prefill), jax.jit(decode)
+    return jax.jit(prefill), jax.jit(decode, donate_argnums=(1,))
 
 
 def build_engine(cfg: ModelConfig, *, max_slots: int, s_max: int,

@@ -131,11 +131,17 @@ def attn_swa(q, k, v, *, window: int, q_offset=0, block_q: int = 512):
     return out.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, D)
 
 
-def attn_decode(q, k, v, *, kv_len, causal: bool = True, q_pos=None):
+def attn_decode(q, k, v, *, kv_len, causal: bool = True, q_pos=None,
+                new=None):
     """q: (B,1,Hq,D) against cache k/v: (B,Smax,Hkv,D), Hq = gq*Hkv.
     GQA is served by a grouped einsum -- the KV cache is *not* repeated
     (a materialized repeat doubles decode HBM traffic, the dominant term
-    of the decode roofline). ``kv_len`` may be per-batch (B,)."""
+    of the decode roofline). ``kv_len`` may be per-batch (B,).
+
+    ``new`` = (k_new, v_new, slot): this step's keys and values (B,Hkv,D)
+    and the cache row (B,) they belong in, which the cache does not hold
+    yet. Row ``slot`` of k/v is left out and the new row attended to in
+    its place, as if it had been written there first."""
     B, _, Hq, D = q.shape
     Smax, Hkv = k.shape[1], k.shape[2]
     gq = Hq // Hkv
@@ -147,10 +153,28 @@ def attn_decode(q, k, v, *, kv_len, causal: bool = True, q_pos=None):
         valid = pos[None, :] < kv_len
     else:
         valid = pos[None, :] < kv_len[:, None]
+    if new is None:
+        s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(B, 1, Hq, D).astype(q.dtype)
+    k_new, v_new, slot = new
+    valid = valid & (pos[None, :] != slot[:, None])
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v,
+    s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new,
+                       preferred_element_type=jnp.float32)
+    # the softmax over the cache's rows and the new one
+    m = jnp.maximum(jnp.max(s, axis=-1), s_new)
+    e = jnp.exp(s - m[..., None])
+    e_new = jnp.exp(s_new - m)
+    total = jnp.sum(e, axis=-1) + e_new
+    p = (e / total[..., None]).astype(v.dtype)
+    p_new = (e_new / total).astype(v.dtype)
+    o = jnp.einsum("bkgs,bskd->bkgd", p, v,
                    preferred_element_type=jnp.float32)
+    o = o + jnp.einsum("bkg,bkd->bkgd", p_new, v_new,
+                       preferred_element_type=jnp.float32)
     return o.reshape(B, 1, Hq, D).astype(q.dtype)
 
 

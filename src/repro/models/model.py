@@ -172,15 +172,33 @@ class Model:
         p_seg = params["blocks"][seg.name]
 
         if seg.kind in ("attn_mlp", "attn_moe"):
+            def ffn(xc, p):
+                if seg.kind == "attn_moe":
+                    return T.block_moe(ops, p, xc, cfg)
+                return T.block_mlp(ops, p, xc, cfg), jnp.float32(0.0)
+
+            if mode == "decode":
+                # the stacked (L, B, S_kv, H, D) cache is read where it
+                # lies, layer by layer, and this step's rows are written
+                # into it once, after the scan: no per-layer slice is
+                # copied out and no second stacked cache is built
+                def body(xc, inp):
+                    p, layer = inp
+                    xc, rows = T.block_attn(ops, p, xc, cfg, rope,
+                                            cache=cache, pos=pos, mode=mode,
+                                            layer=layer)
+                    xc, aux = ffn(xc, p)
+                    return xc, (rows, aux)
+                with cost_scope(seg.count):
+                    x, (rows, auxs) = lax.scan(
+                        body, x, (p_seg, jnp.arange(seg.count)))
+                return x, jnp.sum(auxs), T.write_rows(cfg, cache, rows, pos)
+
             def body(xc, inp):
                 p, c = inp
                 xc, kvc = T.block_attn(ops, p, xc, cfg, rope, cache=c,
                                        pos=pos, mode=mode, s_max=s_max)
-                if seg.kind == "attn_moe":
-                    xc, aux = T.block_moe(ops, p, xc, cfg)
-                else:
-                    xc = T.block_mlp(ops, p, xc, cfg)
-                    aux = jnp.float32(0.0)
+                xc, aux = ffn(xc, p)
                 return xc, ((kvc if kvc is not None else {}), aux)
             return self._scan(body, x, p_seg, cache, seg.count, mode)
 

@@ -195,13 +195,15 @@ def _mlp(ops: Ops, p, hf, cfg: ModelConfig):
 
 
 def block_attn(ops: Ops, p, x, cfg: ModelConfig, rope, cache=None, pos=None,
-               mode: str = "train", s_max: int = 0):
-    """Self-attention sub-block. x: (B,S_loc,d) sharded / (B,S,d)."""
+               mode: str = "train", s_max: int = 0, layer=None):
+    """Self-attention sub-block. x: (B,S_loc,d) sharded / (B,S,d).
+    ``layer``: in decode, ``cache`` is the segment's stacked cache and
+    this block is its ``layer``-th (see ``_cached_attn``)."""
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     hf = ops.seq_unshard(h)
     q, k, v = _qkv(ops, p, hf, cfg, rope)
     if mode == "decode":
-        o, new_cache = _cached_attn(q, k, v, cfg, cache, pos)
+        o, new_cache = _cached_attn(q, k, v, cfg, cache, pos, layer)
     else:
         o = ATT.attention(q, k, v, causal=cfg.causal, window=cfg.window,
                           impl=cfg.attn_impl)
@@ -230,18 +232,49 @@ def _prefill_cache(k, v, cfg: ModelConfig, s_max: int):
     return {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
 
 
-def _cached_attn(q, k, v, cfg: ModelConfig, cache, pos):
+def _cached_attn(q, k, v, cfg: ModelConfig, cache, pos, layer=None):
     """Decode-mode attention against a (ring) cache. q/k/v: (B,1,h,dh);
-    cache: {k,v: (B,Smax,kv_l,dh)}; pos: (B,) absolute positions."""
+    cache: {k,v: (B,Smax,kv_l,dh)}; pos: (B,) absolute positions. With
+    ``layer``, cache is a stacked {k,v: (L,B,Smax,kv_l,dh)} that this
+    step's rows are not in yet: attention reads the layer's slice where
+    it lies, with the new row in place of the one it will overwrite, and
+    the new rows {k,v: (B,kv_l,dh)} are returned for ``write_rows``."""
     B = q.shape[0]
-    Smax = cache["k"].shape[1]
-    slot = pos % Smax if cfg.window else jnp.minimum(pos, Smax - 1)
-    bidx = jnp.arange(B)
-    kc = cache["k"].at[bidx, slot].set(k[:, 0])
-    vc = cache["v"].at[bidx, slot].set(v[:, 0])
+    Smax = cache["k"].shape[-3]
+    slot = _slot(cfg, pos, Smax)
     kv_len = jnp.minimum(pos + 1, Smax)
-    o = ATT.attn_decode(q, kc, vc, kv_len=kv_len)   # grouped: no KV repeat
-    return o, {"k": kc, "v": vc}
+    if layer is None:
+        bidx = jnp.arange(B)
+        kc = cache["k"].at[bidx, slot].set(k[:, 0])
+        vc = cache["v"].at[bidx, slot].set(v[:, 0])
+        o = ATT.attn_decode(q, kc, vc, kv_len=kv_len)  # grouped: no KV repeat
+        return o, {"k": kc, "v": vc}
+    o = ATT.attn_decode(q, cache["k"][layer], cache["v"][layer],
+                        kv_len=kv_len, new=(k[:, 0], v[:, 0], slot))
+    return o, {"k": k[:, 0], "v": v[:, 0]}
+
+
+def _slot(cfg: ModelConfig, pos, Smax: int):
+    """Cache row of absolute position ``pos``: a ring for SWA."""
+    return pos % Smax if cfg.window else jnp.minimum(pos, Smax - 1)
+
+
+def write_rows(cfg: ModelConfig, cache, rows, pos):
+    """Write one decode step's rows {k,v: (L,B,kv_l,dh)} into the stacked
+    cache {k,v: (L,B,Smax,kv_l,dh)}, at each batch row's cache row for
+    ``pos``: one dynamic_update_slice per batch row and leaf, all layers
+    at once. On the TPU these update a donated cache in place, in the
+    layout attention reads it in; a scatter of all rows at once makes
+    the compiler relayout the whole cache around it."""
+    slot = _slot(cfg, pos, cache["k"].shape[2])
+    out = {}
+    for name, c in cache.items():
+        r = rows[name]
+        for b in range(r.shape[1]):
+            c = lax.dynamic_update_slice(c, r[:, b, None, None],
+                                         (0, b, slot[b], 0, 0))
+        out[name] = c
+    return out
 
 
 def block_mlp(ops: Ops, p, x, cfg: ModelConfig):

@@ -10,7 +10,9 @@ continuous-batching trick to keep the compiled shape static.
 
 The engine is deliberately runtime-agnostic: ``prefill_fn``/``decode_fn``
 are the compiled steps from train/step.py, so the same engine drives a
-1-device CPU smoke test and a 512-chip mesh. ``serve/cluster.py`` shards
+1-device CPU smoke test and a 512-chip mesh. ``decode_fn`` may donate
+the cache it is given (``launch.serve.serving_steps`` does): the engine
+keeps only the cache it returns. ``serve/cluster.py`` shards
 replicas of it across a warm ``ExecutorPool``; ``serve/spec.py`` plugs
 draft-model speculative decoding into ``step()``.
 

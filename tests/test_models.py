@@ -1,6 +1,7 @@
 """Per-architecture smoke + decode-parity tests (single device, reduced
 configs -- the full configs are exercised only via the dry-run)."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -8,8 +9,9 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get_config
+from repro.launch.serve import serving_model, serving_steps
 from repro.models.model import Model
-from repro.models.common import gqa_layout
+from repro.models.common import gqa_layout, tree_shapes
 from repro.parallel import axes as A
 from repro.parallel.ops import ParallelConfig, make_ops
 
@@ -114,6 +116,79 @@ def test_decode_matches_forward(arch):
             np.asarray(logits), np.asarray(full_logits[:, t]),
             atol=3e-3, rtol=3e-3,
             err_msg=f"{arch}: decode diverges at position {t}")
+
+
+def _random_cache(model, batch, s_max, dtype, key=KEY):
+    shapes = tree_shapes(model.cache_specs(batch, s_max), dtype=dtype)
+    leaves, treedef = jax.tree.flatten(shapes)
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree.unflatten(treedef, [
+        jax.random.normal(k, s.shape, jnp.float32).astype(s.dtype)
+        for s, k in zip(leaves, keys)])
+
+
+@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "deepseek-moe-16b"])
+def test_donated_decode_wraps_the_ring_like_the_plain_loop(arch):
+    """``serving_steps``' decode, which donates its cache, run 20 steps
+    over an 8-row ring from three different offsets (each slot wraps
+    twice): its logits and caches equal a plain un-donated
+    ``Model.decode`` loop's, the cache it was given is consumed, and each
+    step changes only the row it writes in each slot."""
+    cfg = dataclasses.replace(get_config(arch, smoke=True), window=8)
+    model = serving_model(cfg)
+    params = model.init(KEY)
+    s_max, B = 8, 3
+    _, donated = serving_steps(model, s_max)
+    ops = make_ops(model.axes, model.pcfg)
+    plain = jax.jit(lambda p, c, t, pos: model.decode(ops, p, c, t, pos))
+    c_plain = _random_cache(model, B, s_max, cfg.dtype)
+    c_don = jax.tree.map(jnp.copy, c_plain)
+    pos0 = np.array([5, 0, 3], np.int32)
+    toks = np.random.default_rng(0).integers(0, cfg.vocab, (20, B, 1))
+    host = lambda tree: jax.tree.map(lambda a: np.asarray(jnp.copy(a)), tree)
+    attn = {seg.name for seg in model.schedule
+            if seg.kind in ("attn_mlp", "attn_moe")}
+    assert attn
+    for t in range(20):
+        pos = jnp.asarray(pos0 + t)
+        tok = jnp.asarray(toks[t], jnp.int32)
+        # host views of device copies: a host view of a CPU array pins its
+        # buffer, and a pinned buffer is copied rather than donated
+        before = host(c_don)
+        given = jax.tree.leaves(c_don)
+        want, c_plain = plain(params, c_plain, tok, pos)
+        got, c_don = donated(params, c_don, tok, pos)
+        assert all(a.is_deleted() for a in given)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        after = host(c_don)
+        jax.tree.map(np.testing.assert_array_equal, after, host(c_plain))
+        written = np.zeros((B, s_max), bool)
+        written[np.arange(B), (pos0 + t) % s_max] = True
+        for name in attn:
+            for leaf in ("k", "v"):
+                old, new = before[name][leaf], after[name][leaf]
+                np.testing.assert_array_equal(new[:, ~written],
+                                              old[:, ~written])
+                assert not np.array_equal(new[:, written], old[:, written])
+
+
+@pytest.mark.parametrize("arch", DECODE_ARCHS)
+def test_serving_decode_aliases_its_cache(arch):
+    """The lowered serving decode donates every cache leaf to the cache
+    it returns: each is marked as aliasing an output."""
+    cfg = get_config(arch, smoke=True)
+    model = serving_model(cfg)
+    _, decode = serving_steps(model, 16)
+    B = 2
+    params = jax.eval_shape(model.init, KEY)
+    caches = tree_shapes(model.cache_specs(B, 16), dtype=cfg.dtype)
+    text = decode.lower(params, caches,
+                        jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                        jax.ShapeDtypeStruct((B,), jnp.int32)).as_text()
+    aliased = re.findall(r"tf\.aliasing_output = (\d+)", text)
+    n_cache = len(jax.tree.leaves(caches))
+    # outputs: logits first, then the cache leaves in order
+    assert sorted(map(int, aliased)) == list(range(1, n_cache + 1))
 
 
 def test_gqa_layout_invariants():

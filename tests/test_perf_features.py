@@ -5,6 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
@@ -122,3 +123,31 @@ def test_decode_grouped_attention_matches_repeat():
     out_rep = attn_decode(q, jnp.repeat(k, 4, 2), jnp.repeat(v, 4, 2),
                           kv_len=kv_len)
     np.testing.assert_allclose(out, out_rep, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+def test_decode_new_row_folded_in_matches_written_row(dtype, tol):
+    """Attending to a cache that does not hold this step's rows yet, with
+    them folded in (``new=``), equals writing them at their slot first:
+    a slot still filling (kv_len < S, a stale row at the slot), one whose
+    ring wrapped (kv_len == S), and one at the last row. Only the order
+    of the softmax sums differs, hence a tolerance of the dtype's."""
+    from repro.models.attention import attn_decode
+    B, S, Hq, Hkv, D = 3, 16, 8, 2, 32
+    keys = jax.random.split(KEY, 5)
+    q = jax.random.normal(keys[0], (B, 1, Hq, D)).astype(dtype)
+    k = jax.random.normal(keys[1], (B, S, Hkv, D)).astype(dtype)
+    v = jax.random.normal(keys[2], (B, S, Hkv, D)).astype(dtype)
+    k_new = jax.random.normal(keys[3], (B, Hkv, D)).astype(dtype)
+    v_new = jax.random.normal(keys[4], (B, Hkv, D)).astype(dtype)
+    pos = jnp.asarray([5, 21, 15])                 # absolute positions
+    slot, kv_len = pos % S, jnp.minimum(pos + 1, S)
+    rows = jnp.arange(B)
+    want = attn_decode(q, k.at[rows, slot].set(k_new),
+                       v.at[rows, slot].set(v_new), kv_len=kv_len)
+    got = attn_decode(q, k, v, kv_len=kv_len, new=(k_new, v_new, slot))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)

@@ -6,7 +6,9 @@ Nothing runs; the TPU compiler refuses here what the chip would refuse
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every test worker imports this
 file."""
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,3 +101,65 @@ def test_danube_decode_step_compiles_at_full_width(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16e9, used           # one v5e chip holds 16 GB
+
+
+def _hlo_instructions(text):
+    """(computation, name, opcode, dims, operand names) of every
+    instruction of a compiled module's text, and the set of fused
+    computations (the bodies of ``fusion`` instructions)."""
+    out, fused, comp = [], set(), None
+    head = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+    inst = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\(([^)]*)\)")
+    for line in text.splitlines():
+        m = head.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        if " fusion(" in line:          # array- or tuple-shaped
+            fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+        m = inst.match(line)
+        if m:
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            out.append((comp, m.group(1), m.group(3), dims,
+                        re.findall(r"%([\w.\-]+)", m.group(4))))
+    return out, fused
+
+
+def test_danube_decode_writes_its_cache_in_place(one_chip):
+    """At 16 slots over a 4096-token cache the decode program writes each
+    layer's new K/V rows into the donated cache and reads the layer where
+    it lies: no instruction outside a fusion copies or slices out a
+    layer's K or V, no update writes that much, and the program allocates
+    less than one layer's K besides the cache it aliases."""
+    cfg = get_config("h2o-danube-1.8b")
+    slots, s_max = 16, 4096
+    model = serving_model(cfg)
+    _, decode = serving_steps(model, s_max)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    caches = on_chip(tree_shapes(model.cache_specs(slots, s_max),
+                                 dtype=cfg.dtype), one_chip)
+    compiled = decode.lower(params, caches,
+                            shaped((slots, 1), jnp.int32, one_chip),
+                            shaped((slots,), jnp.int32, one_chip)).compile()
+    layer = slots * s_max * cfg.n_kv_heads * cfg.dh   # one layer's K or V
+    layer_bytes = layer * jnp.dtype(cfg.dtype).itemsize
+    cache_bytes = sum(c.size * c.dtype.itemsize
+                      for c in jax.tree.leaves(caches))
+    insts, fused = _hlo_instructions(compiled.as_text())
+    shape_of = {(c, n): d for c, n, _, d, _ in insts}
+    moved = [n for c, n, op, d, _ in insts if c not in fused
+             and op not in ("parameter", "get-tuple-element", "bitcast")
+             and (math.prod(d) == layer
+                  or op == "copy" and math.prod(d) >= layer)]
+    written = [n for c, n, op, _, args in insts
+               if op == "dynamic-update-slice"
+               and math.prod(shape_of.get((c, args[1]), ())) >= layer]
+    assert not moved, moved
+    assert not written, written
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    fresh = (mem.output_size_in_bytes - mem.alias_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert fresh < layer_bytes, fresh
