@@ -24,6 +24,7 @@ ARCH_MODULES = {
     "qwen3-4b": "qwen3_4b",
     "xlstm-125m": "xlstm_125m",
     "llama-3.2-vision-11b": "llama32_vision_11b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCHS = tuple(ARCH_MODULES)
@@ -60,7 +61,7 @@ def skip_reason(cfg: ModelConfig, shape: Shape) -> str | None:
 
 
 def cell_plan() -> list[dict]:
-    """All 40 cells; runnable ones have skip=None."""
+    """Every (arch, shape) cell; runnable ones have skip=None."""
     out = []
     for arch in ARCHS:
         cfg = get_config(arch)

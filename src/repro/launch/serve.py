@@ -54,14 +54,19 @@ def serving_model(cfg: ModelConfig) -> Model:
 def serving_steps(model: Model, s_max: int):
     """The jitted (prefill, decode) programs the engine runs. Decode
     donates its cache argument: the cache it returns is written into the
-    same buffers, so a caller must not read a cache it passed to decode."""
+    same buffers, so a caller must not read a cache it passed to decode.
+    Each returns (logits, caches) and, for a model with expert layers,
+    their counts third (``moe.moe_ffn``'s, summed over the layers), which
+    the engine fetches with the step's tokens."""
     ops = make_ops(model.axes, model.pcfg)
 
     def prefill(params, batch):
-        return model.prefill(ops, params, batch, s_max=s_max)
+        out = model.prefill(ops, params, batch, s_max=s_max, counts=True)
+        return out if out[2] else out[:2]
 
     def decode(params, caches, tokens, pos):
-        return model.decode(ops, params, caches, tokens, pos)
+        out = model.decode(ops, params, caches, tokens, pos, counts=True)
+        return out if out[2] else out[:2]
 
     return jax.jit(prefill), jax.jit(decode, donate_argnums=(1,))
 
@@ -89,18 +94,18 @@ class LogitWatch:
         eng.prefill_fn, eng.decode_fn = self.prefill, self.decode
 
     def prefill(self, params, batch):
-        logits, caches = self._prefill(params, batch)
-        self._finite.append(jnp.isfinite(logits).all())
-        return logits, caches
+        out = self._prefill(params, batch)
+        self._finite.append(jnp.isfinite(out[0]).all())
+        return out
 
     def decode(self, params, caches, tokens, pos):
-        logits, caches = self._decode(params, caches, tokens, pos)
-        self._finite.append(jnp.isfinite(logits).all())
+        out = self._decode(params, caches, tokens, pos)
+        self._finite.append(jnp.isfinite(out[0]).all())
         if self.first_decode is None:
             # host copies: on the CPU backend the device arrays may share
             # memory with the engine's numpy state, which it then advances
-            self.first_decode = (np.array(tokens), np.array(pos), logits)
-        return logits, caches
+            self.first_decode = (np.array(tokens), np.array(pos), out[0])
+        return out
 
     def all_finite(self) -> bool:
         return bool(self._finite) and all(bool(f) for f in self._finite)
@@ -120,7 +125,7 @@ def decode_prefill_gap(eng: Engine, watch: LogitWatch, slot: int,
             f" at position {int(pos[slot])}; expected {first_token} at "
             f"{len(prompt)}")
     ext = np.append(np.asarray(prompt, np.int32), np.int32(first_token))
-    want, _ = eng.prefill_fn(eng.params, {"tokens": jnp.asarray(ext)[None]})
+    want = eng.prefill_fn(eng.params, {"tokens": jnp.asarray(ext)[None]})[0]
     want = np.asarray(want[0], np.float32)
     got = np.asarray(logits[slot], np.float32)
     return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))

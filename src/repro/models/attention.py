@@ -10,6 +10,8 @@ Three cores:
 - ``attn_swa``      : scan over Q blocks; each gathers its KV window slice
                       (FLOPs scale with S*window, not S^2).
 - ``attn_decode``   : single-query against a (ring-buffered) cache.
+- ``attn_latent_decode`` : latent attention (MLA) decode, absorbed form,
+                      against the cached latents and rotary keys.
 """
 from __future__ import annotations
 
@@ -47,22 +49,24 @@ def attention(q, k, v, *, causal: bool, window: int = 0, q_offset=0,
                         block_k=block_k)
 
 
-def attn_kv_scan(q, k, v, *, causal: bool, q_offset=0, block_k: int = 512):
-    """Online-softmax over KV blocks. q: (B,Sq,H,D), k/v: (B,Sk,H,D)."""
+def attn_kv_scan(q, k, v, *, causal: bool, q_offset=0, block_k: int = 512,
+                 scale: float | None = None):
+    """Online-softmax over KV blocks. q/k: (B,Sq|Sk,H,D), v: (B,Sk,H,Dv);
+    ``scale`` defaults to D**-0.5."""
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     block_k = min(block_k, Sk)
     n_blk = -(-Sk // block_k)
     pad = n_blk * block_k - Sk
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     qf = (q * scale).astype(q.dtype)
     q_pos = q_offset + jnp.arange(Sq)
 
     kb = k.reshape(B, n_blk, block_k, H, D).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, n_blk, block_k, H, D).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(B, n_blk, block_k, H, Dv).transpose(1, 0, 2, 3, 4)
 
     def step(carry, blk):
         acc, m, l = carry
@@ -83,7 +87,7 @@ def attn_kv_scan(q, k, v, *, causal: bool, q_offset=0, block_k: int = 512):
         acc = acc * corr.transpose(0, 2, 1)[..., None] + pv
         return (acc, m_new, l), None
 
-    acc0 = jnp.zeros((B, Sq, H, D), jnp.float32)
+    acc0 = jnp.zeros((B, Sq, H, Dv), jnp.float32)
     m0 = jnp.full((B, H, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, H, Sq), jnp.float32)
     (acc, m, l), _ = lax.scan(step, (acc0, m0, l0),
@@ -176,6 +180,38 @@ def attn_decode(q, k, v, *, kv_len, causal: bool = True, q_pos=None,
     o = o + jnp.einsum("bkg,bkd->bkgd", p_new, v_new,
                        preferred_element_type=jnp.float32)
     return o.reshape(B, 1, Hq, D).astype(q.dtype)
+
+
+def attn_latent_decode(q_lat, q_pe, c, kr, *, kv_len, new, scale: float):
+    """Absorbed latent attention of one decode step (DeepSeek-V2's MLA).
+    q_lat: (B,H,R) queries already multiplied into the latent space
+    (W_uk^T q_nope); q_pe: (B,H,Dr) rotary queries; c: (B,Smax,R) cached
+    latents and kr: (B,Smax,Dr) cached rotary keys, shared by every head.
+    ``new`` = (c_new (B,R), kr_new (B,Dr), slot (B,)) is this step's row,
+    not in the cache yet: row ``slot`` is left out and the new row
+    attended to in its place, as ``attn_decode`` does. Returns the
+    attention-weighted latents (B,H,R) in float32, which W_uv maps to
+    each head's values. A score is (q_lat.c + q_pe.kr) * scale."""
+    c_new, kr_new, slot = new
+    Smax = c.shape[1]
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, c,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhd,bsd->bhs", q_pe, kr,
+                      preferred_element_type=jnp.float32)) * scale
+    s_new = (jnp.einsum("bhr,br->bh", q_lat, c_new,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhd,bd->bh", q_pe, kr_new,
+                          preferred_element_type=jnp.float32)) * scale
+    pos = jnp.arange(Smax)
+    valid = (pos[None, :] < kv_len[:, None]) & (pos[None, :] != slot[:, None])
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    m = jnp.maximum(jnp.max(s, axis=-1), s_new)
+    e = jnp.exp(s - m[..., None])
+    e_new = jnp.exp(s_new - m)
+    total = jnp.sum(e, axis=-1) + e_new
+    o = jnp.einsum("bhs,bsr->bhr", (e / total[..., None]).astype(c.dtype), c,
+                   preferred_element_type=jnp.float32)
+    return o + (e_new / total)[..., None] * c_new.astype(jnp.float32)[:, None]
 
 
 def attn_cross(q, k, v):

@@ -1,4 +1,4 @@
-"""Shared model-configuration & parameter machinery for all 10 architectures.
+"""Shared model-configuration & parameter machinery for every registry architecture.
 
 One ``ModelConfig`` covers the dense / MoE / hybrid-SSM / xLSTM / VLM / audio
 families; per-arch files in ``repro/configs`` fill it in. Parameters are
@@ -30,6 +30,22 @@ from ..parallel import axes as A
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type "yarn"):
+    the rotary frequencies ramp from interpolated (divided by ``factor``)
+    to extrapolated between the correction dimensions that ``beta_fast``
+    and ``beta_slow`` rotations over ``original_max`` positions give,
+    and attention scores are scaled by ``mscale(factor, mscale_all_dim)``
+    squared (see ``layers.yarn_inv_freq`` and ``yarn_mscale``)."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     kind: str                       # dense | moe | hybrid | xlstm
@@ -46,6 +62,12 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     rope_pct: float = 1.0           # fraction of head_dim that is rotated
+    rope_yarn: YaRN | None = None   # YaRN-scaled rotary frequencies
+    # --- latent attention (MLA, DeepSeek-V2); kv_lora_rank > 0 selects it
+    kv_lora_rank: int = 0           # width of the cached latent c
+    qk_nope_head_dim: int = 0       # per-head query/key part without rotary
+    qk_rope_head_dim: int = 0       # rotary part; one key shared by all heads
+    v_head_dim: int = 0
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
@@ -54,6 +76,8 @@ class ModelConfig:
     dense_residual: bool = False    # arctic: dense FFN in parallel with MoE
     first_dense_layers: int = 0     # deepseek: leading dense layers
     capacity_factor: float = 1.25
+    experts_held: int = 0           # routed experts this layer holds; 0: all
+    norm_topk_prob: bool = True     # renormalise the top-k router weights
     router_aux_coef: float = 0.01
     # --- hybrid (zamba2-style Mamba2 + shared attention) ---
     ssm_state: int = 0              # N (d_state)
@@ -87,9 +111,19 @@ class ModelConfig:
     def is_encoder(self) -> bool:
         return not self.causal
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
     def validate(self) -> "ModelConfig":
         if self.kind == "moe":
             assert self.n_experts > 0 and self.top_k > 0 and self.moe_d_ff > 0
+            if not 0 <= self.experts_held <= self.n_experts:
+                raise ValueError("experts_held must lie in [0, n_experts]")
+        if self.mla and (self.window or self.attn_impl != "xla"
+                         or self.n_kv_heads != self.n_heads):
+            raise ValueError("latent attention runs full-context on the "
+                             "xla path, one key head per query head")
         if self.kind == "hybrid":
             assert self.ssm_state > 0 and self.attn_every > 0
         if self.n_heads % max(self.n_kv_heads, 1):

@@ -6,13 +6,17 @@ path and global arrays on the gspmd path.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..parallel import axes as A
 from ..parallel.ops import Ops
+from .common import YaRN
 
 
 def rmsnorm(x, w, eps: float = 1e-5):
@@ -22,11 +26,49 @@ def rmsnorm(x, w, eps: float = 1e-5):
     return (x * w.astype(jnp.float32)).astype(dt)
 
 
-def rope_angles(positions, dh_rot: int, theta: float):
+def rope_angles(positions, dh_rot: int, theta: float,
+                yarn: YaRN | None = None):
     """positions: int32 (...,); returns cos/sin of shape (..., dh_rot//2)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dh_rot, 2, dtype=jnp.float32) / dh_rot))
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, dh_rot, 2, dtype=jnp.float32)
+                               / dh_rot))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(dh_rot, theta, yarn))
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dh_rot: int, theta: float, yarn: YaRN) -> np.ndarray:
+    """(dh_rot//2,) YaRN inverse frequencies, as DeepSeek-V2 computes
+    them: pairs below the correction range keep theta's frequency, pairs
+    above it are divided by ``factor``, and a linear ramp joins them. The
+    range is where ``beta_fast`` and ``beta_slow`` full rotations fit in
+    ``original_max`` positions (``yarn_find_correction_range``)."""
+    def corr_dim(rotations):
+        return (dh_rot * math.log(yarn.original_max
+                                  / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dh_rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dh_rot // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extra = 1.0 / theta ** (np.arange(0, dh_rot, 2, dtype=np.float32)
+                            / dh_rot)
+    return (extra / yarn.factor * ramp + extra * (1.0 - ramp)
+            ).astype(np.float32)
 
 
 def apply_rope(x, cos, sin, rope_pct: float = 1.0):

@@ -1,5 +1,5 @@
 """Model facade: specs/init + loss / prefill / decode over the segment
-schedule, for any of the 10 architectures, on either distribution path.
+schedule, for any registry architecture, on either distribution path.
 
 Everything that must agree between the training step, the serving steps,
 the dry-run lowering and the checkpointer (shapes, PartitionSpecs, layer
@@ -148,23 +148,38 @@ class Model:
 
     def _rope(self, positions):
         cfg = self.cfg
-        d_rot = int(cfg.dh * cfg.rope_pct) // 2 * 2
+        d_rot = (cfg.qk_rope_head_dim if cfg.mla
+                 else int(cfg.dh * cfg.rope_pct) // 2 * 2)
         if d_rot == 0:
             return None
-        return rope_angles(positions, d_rot, cfg.rope_theta)
+        return rope_angles(positions, d_rot, cfg.rope_theta, cfg.rope_yarn)
 
     def forward(self, ops: Ops, params, x, rope, img, mode: str,
                 caches=None, pos=None, s_max: int = 0):
         """Run all segments. Returns (x, aux_sum, new_caches)."""
+        x, aux, new_caches, _ = self._forward(ops, params, x, rope, img,
+                                              mode, caches, pos, s_max)
+        return x, aux, new_caches
+
+    def _forward(self, ops: Ops, params, x, rope, img, mode: str,
+                 caches=None, pos=None, s_max: int = 0):
+        """``forward``, and the expert layers' counts summed over the
+        layers, as {"moe.<name>": int32} for each count ``moe.moe_ffn``
+        returns: {} for a model without expert layers."""
         aux_total = jnp.float32(0.0)
-        new_caches = {}
+        new_caches, counts = {}, {}
         for seg in self.schedule:
             c = None if caches is None else caches[seg.name]
             x, aux, nc = self._run_seg(ops, seg, params, x, rope, img,
                                        mode, c, pos, s_max)
+            if isinstance(aux, dict):       # an expert segment's
+                for name, v in aux["counts"].items():
+                    name = "moe." + name
+                    counts[name] = counts.get(name, 0) + v
+                aux = aux["aux"]
             aux_total = aux_total + aux
             new_caches[seg.name] = nc
-        return x, aux_total, new_caches
+        return x, aux_total, new_caches, counts
 
     def _run_seg(self, ops: Ops, seg, params, x, rope, img, mode,
                  cache, pos, s_max):
@@ -172,9 +187,12 @@ class Model:
         p_seg = params["blocks"][seg.name]
 
         if seg.kind in ("attn_mlp", "attn_moe"):
+            # an expert segment's per-layer aux is {"aux", "counts"}, which
+            # the scans sum over the layers leaf by leaf
             def ffn(xc, p):
                 if seg.kind == "attn_moe":
-                    return T.block_moe(ops, p, xc, cfg)
+                    xc, aux, counts = T.block_moe(ops, p, xc, cfg)
+                    return xc, {"aux": aux, "counts": counts}
                 return T.block_mlp(ops, p, xc, cfg), jnp.float32(0.0)
 
             if mode == "decode":
@@ -192,7 +210,8 @@ class Model:
                 with cost_scope(seg.count):
                     x, (rows, auxs) = lax.scan(
                         body, x, (p_seg, jnp.arange(seg.count)))
-                return x, jnp.sum(auxs), T.write_rows(cfg, cache, rows, pos)
+                return (x, jax.tree.map(jnp.sum, auxs),
+                        T.write_rows(cfg, cache, rows, pos))
 
             def body(xc, inp):
                 p, c = inp
@@ -273,7 +292,8 @@ class Model:
         else:
             with cost_scope(count):
                 x, (caches, auxs) = lax.scan(body, x, (p_seg, cache))
-        return x, jnp.sum(auxs), (caches if mode != "train" else None)
+        return (x, jax.tree.map(jnp.sum, auxs),
+                (caches if mode != "train" else None))
 
     def _scan_inner(self, inner, x, p_inner, cache_inner, count, mode):
         if mode == "train" and self.pcfg.remat != "none":
@@ -327,32 +347,39 @@ class Model:
         return loss, metrics
 
     # --------------------------------------------------------------- serving
-    def prefill(self, ops: Ops, params, batch, s_max: int):
-        """Forward + cache build. Returns (last_token_logits, caches)."""
+    def prefill(self, ops: Ops, params, batch, s_max: int,
+                counts: bool = False):
+        """Forward + cache build. Returns (last_token_logits, caches), and
+        with ``counts`` the expert layers' counts summed over the layers
+        ({"moe.<name>": int32}; {} for a model without expert layers)
+        third."""
         cfg = self.cfg
         x, img = self._embed_in(ops, params, batch)
         S = (batch["tokens"] if cfg.input_mode == "tokens"
              else batch["frames"]).shape[1]
         rope = self._rope(jnp.arange(S))
-        x, _, caches = self.forward(ops, params, x, rope, img, "prefill",
-                                    s_max=s_max)
+        x, _, caches, n = self._forward(ops, params, x, rope, img, "prefill",
+                                        s_max=s_max)
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         xf = ops.seq_unshard(x)
         logits = logits_only(ops, params["head"], xf[:, -1:], self.v_pad,
                              cfg.vocab)
-        return logits[:, 0], caches
+        return (logits[:, 0], caches) + ((n,) if counts else ())
 
-    def decode(self, ops: Ops, params, caches, tokens, pos):
+    def decode(self, ops: Ops, params, caches, tokens, pos,
+               counts: bool = False):
         """One decode step. tokens: (B, 1) int32; pos: (B,) absolute
-        positions of these tokens. Returns (logits (B, vocab), caches)."""
+        positions of these tokens. Returns (logits (B, vocab), caches),
+        and the expert layers' counts third with ``counts`` (as
+        ``prefill``)."""
         cfg = self.cfg
         x, _ = self._embed_in(ops, params, {"tokens": tokens})
         rope = self._rope(pos[:, None])               # (B,1,d_rot/2)
-        x, _, new_caches = self.forward(ops, params, x, rope, None,
-                                        "decode", caches=caches, pos=pos)
+        x, _, new_caches, n = self._forward(ops, params, x, rope, None,
+                                            "decode", caches=caches, pos=pos)
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         logits = logits_only(ops, params["head"], x, self.v_pad, cfg.vocab)
-        return logits[:, 0], new_caches
+        return (logits[:, 0], new_caches) + ((n,) if counts else ())
 
     # ----------------------------------------------------------- cache specs
     def cache_specs(self, batch: int, s_max: int):
@@ -363,6 +390,12 @@ class Model:
         s_kv = min(cfg.window, s_max) if cfg.window else s_max
 
         def kv(count):
+            if cfg.mla:         # the latent and the shared rotary key
+                return {name: ParamSpec((count, batch, s_max, w),
+                                        P(None, bsp, None, None),
+                                        init="zeros")
+                        for name, w in (("c", cfg.kv_lora_rank),
+                                        ("kr", cfg.qk_rope_head_dim))}
             shp = (count, batch, s_kv, lay.kv_eff, dh)
             return {"k": ParamSpec(shp, P(None, bsp, None, A.MODEL_AXIS,
                                           None), init="zeros"),

@@ -30,7 +30,7 @@ from . import ssm as SSM
 from . import xlstm as XL
 from .common import (GQALayout, ModelConfig, ParamSpec, dense_col, dense_row,
                      head_mask, replicated, stacked)
-from .layers import apply_rope, rmsnorm
+from .layers import apply_rope, rmsnorm, yarn_mscale
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +96,22 @@ def attn_specs(cfg: ModelConfig, layout: GQALayout) -> dict:
     return sp
 
 
+def mla_specs(cfg: ModelConfig) -> dict:
+    """Latent attention (no query compression, DeepSeek-V2-Lite): per-head
+    query columns [nope | rope], the shared down-projection to the latent
+    and the rotary key, the latent's norm, the per-head up-projection
+    [k_nope | v] and the output projection. Heads shard over `model`;
+    the down-projection and its norm are replicated."""
+    d, H, R = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {"ln1": replicated(d),
+            "wq": dense_col(d, H * (dn + dr)),
+            "w_dkv": ParamSpec((d, R + dr), P(A.DATA_AXIS, None)),
+            "kv_norm": replicated(R),
+            "w_ukv": dense_col(R, H * (dn + dv)),
+            "wo": dense_row(H * dv, d, fan_in=cfg.n_layers)}
+
+
 def mlp_specs(cfg: ModelConfig, d_ff: int | None = None) -> dict:
     d = cfg.d_model
     f = d_ff or cfg.d_ff
@@ -108,10 +124,11 @@ def mlp_specs(cfg: ModelConfig, d_ff: int | None = None) -> dict:
 
 
 def layer_specs(cfg: ModelConfig, layout: GQALayout, kind: str) -> dict:
+    attn = mla_specs(cfg) if cfg.mla else attn_specs(cfg, layout)
     if kind == "attn_mlp":
-        return {**attn_specs(cfg, layout), **mlp_specs(cfg)}
+        return {**attn, **mlp_specs(cfg)}
     if kind == "attn_moe":
-        sp = {**attn_specs(cfg, layout), "ln2": replicated(cfg.d_model)}
+        sp = {**attn, "ln2": replicated(cfg.d_model)}
         sp["moe"] = MOE.moe_param_specs(cfg)
         pd = cfg.n_shared_experts * cfg.moe_d_ff
         if cfg.dense_residual:
@@ -199,6 +216,8 @@ def block_attn(ops: Ops, p, x, cfg: ModelConfig, rope, cache=None, pos=None,
     """Self-attention sub-block. x: (B,S_loc,d) sharded / (B,S,d).
     ``layer``: in decode, ``cache`` is the segment's stacked cache and
     this block is its ``layer``-th (see ``_cached_attn``)."""
+    if cfg.mla:
+        return block_mla(ops, p, x, cfg, rope, cache, pos, mode, s_max, layer)
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     hf = ops.seq_unshard(h)
     q, k, v = _qkv(ops, p, hf, cfg, rope)
@@ -212,6 +231,68 @@ def block_attn(ops: Ops, p, x, cfg: ModelConfig, rope, cache=None, pos=None,
     B, S = hf.shape[:2]
     o = o.reshape(B, S, -1)
     o = o @ ops.weight(p["wo"], P(A.MODEL_AXIS, A.DATA_AXIS))
+    return x + ops.seq_shard(o), new_cache
+
+
+def block_mla(ops: Ops, p, x, cfg: ModelConfig, rope, cache=None, pos=None,
+              mode: str = "train", s_max: int = 0, layer=None):
+    """Latent attention (DeepSeek-V2 MLA) sub-block. Train and prefill run
+    the expanded form: per-head keys [k_nope | k_pe] and values from the
+    latent, through the blocked causal scan. Decode runs the absorbed
+    form against the latent cache {c: (L,B,Smax,R), kr: (L,B,Smax,Dr)}
+    (``layer`` as in ``block_attn``): q_nope moves into the latent space
+    through W_uk, attention weights the cached latents, and W_uv maps the
+    result to each head's values, so no per-position key or value is
+    ever formed. Returns (x, prefill cache | decode rows | None)."""
+    B = x.shape[0]
+    R, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    hf = ops.seq_unshard(h)
+    S = hf.shape[1]
+    cos, sin = rope
+    q = (hf @ ops.weight(p["wq"], P(A.DATA_AXIS, A.MODEL_AXIS))
+         ).reshape(B, S, -1, dn + dr)
+    q_nope, q_pe = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+    ckv = hf @ ops.weight(p["w_dkv"], P(A.DATA_AXIS, None))
+    c = rmsnorm(ckv[..., :R], p["kv_norm"], cfg.norm_eps)        # (B,S,R)
+    kr = apply_rope(ckv[..., None, R:], cos, sin)[:, :, 0]       # (B,S,Dr)
+    w_ukv = ops.weight(p["w_ukv"], P(A.DATA_AXIS, A.MODEL_AXIS)
+                       ).reshape(R, -1, dn + dv)
+    scale = (dn + dr) ** -0.5
+    if cfg.rope_yarn is not None and cfg.rope_yarn.mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_yarn.factor,
+                             cfg.rope_yarn.mscale_all_dim) ** 2
+    if mode == "decode":
+        Smax = cache["c"].shape[2]
+        slot = _slot(cfg, pos, Smax)
+        with jax.named_scope("mla.absorb"):
+            q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_ukv[..., :dn],
+                               preferred_element_type=jnp.float32)
+        with jax.named_scope("mla.latent_attn"):
+            o_lat = ATT.attn_latent_decode(
+                q_lat.astype(c.dtype), q_pe[:, 0], cache["c"][layer],
+                cache["kr"][layer], kv_len=jnp.minimum(pos + 1, Smax),
+                new=(c[:, 0], kr[:, 0], slot), scale=scale)
+        with jax.named_scope("mla.absorb"):
+            o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(c.dtype),
+                           w_ukv[..., dn:],
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)[:, None]
+        new_cache = {"c": c[:, 0], "kr": kr[:, 0]}
+    else:
+        kv = jnp.einsum("bsr,rhe->bshe", c, w_ukv)
+        H = kv.shape[2]
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(kr[:, :, None], (B, S, H, dr))],
+            axis=-1)
+        o = ATT.attn_kv_scan(jnp.concatenate([q_nope, q_pe], -1), k,
+                             kv[..., dn:], causal=cfg.causal, scale=scale)
+        new_cache = None
+        if mode == "prefill":
+            pad = ((0, 0), (0, s_max - S), (0, 0))
+            new_cache = {"c": jnp.pad(c, pad), "kr": jnp.pad(kr, pad)}
+    o = o.reshape(B, S, -1) @ ops.weight(p["wo"], P(A.MODEL_AXIS, A.DATA_AXIS))
     return x + ops.seq_shard(o), new_cache
 
 
@@ -260,19 +341,21 @@ def _slot(cfg: ModelConfig, pos, Smax: int):
 
 
 def write_rows(cfg: ModelConfig, cache, rows, pos):
-    """Write one decode step's rows {k,v: (L,B,kv_l,dh)} into the stacked
-    cache {k,v: (L,B,Smax,kv_l,dh)}, at each batch row's cache row for
+    """Write one decode step's rows (each leaf (L,B,...): {k,v:
+    (L,B,kv_l,dh)}, or MLA's {c: (L,B,R), kr: (L,B,Dr)}) into the stacked
+    cache (each leaf (L,B,Smax,...)), at each batch row's cache row for
     ``pos``: one dynamic_update_slice per batch row and leaf, all layers
     at once. On the TPU these update a donated cache in place, in the
     layout attention reads it in; a scatter of all rows at once makes
     the compiler relayout the whole cache around it."""
-    slot = _slot(cfg, pos, cache["k"].shape[2])
+    slot = _slot(cfg, pos, next(iter(cache.values())).shape[2])
     out = {}
     for name, c in cache.items():
         r = rows[name]
+        tail = (0,) * (c.ndim - 3)
         for b in range(r.shape[1]):
-            c = lax.dynamic_update_slice(c, r[:, b, None, None],
-                                         (0, b, slot[b], 0, 0))
+            c = lax.dynamic_update_slice(c, r[:, b][:, None, None],
+                                         (0, b, slot[b]) + tail)
         out[name] = c
     return out
 
@@ -284,7 +367,8 @@ def block_mlp(ops: Ops, p, x, cfg: ModelConfig):
 
 
 def block_moe(ops: Ops, p, x, cfg: ModelConfig):
-    """MoE sub-block (+ optional parallel dense branch). Returns (x, aux).
+    """MoE sub-block (+ optional parallel dense branch). Returns (x, aux,
+    counts) with ``moe_ffn``'s counts.
 
     Token layout cases (mpignite path): sequence-parallel training hands
     each model shard its own token slice (all-to-all dispatch); without SP
@@ -303,8 +387,8 @@ def block_moe(ops: Ops, p, x, cfg: ModelConfig):
             sliced = True
     replicated = shard and not ops.pcfg.sequence_parallel and not sliced
     Bh, Sh, d = h_tok.shape
-    routed, aux = MOE.moe_ffn(ops, p["moe"], h_tok.reshape(-1, d), cfg,
-                              tokens_replicated=replicated)
+    routed, aux, counts = MOE.moe_ffn(ops, p["moe"], h_tok.reshape(-1, d),
+                                      cfg, tokens_replicated=replicated)
     routed = routed.reshape(Bh, Sh, d)
     if sliced:
         routed = ops.tp_all_gather(routed, dim=1)
@@ -312,7 +396,7 @@ def block_moe(ops: Ops, p, x, cfg: ModelConfig):
     if "par" in p:
         hf = ops.seq_unshard(h)
         out = out + ops.seq_shard(_mlp(ops, p["par"], hf, cfg))
-    return x + out, aux
+    return x + out, aux, counts
 
 
 def block_mamba(ops: Ops, p, x, cfg: ModelConfig, cache=None,

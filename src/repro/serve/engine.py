@@ -12,7 +12,11 @@ The engine is deliberately runtime-agnostic: ``prefill_fn``/``decode_fn``
 are the compiled steps from train/step.py, so the same engine drives a
 1-device CPU smoke test and a 512-chip mesh. ``decode_fn`` may donate
 the cache it is given (``launch.serve.serving_steps`` does): the engine
-keeps only the cache it returns. ``serve/cluster.py`` shards
+keeps only the cache it returns. Either step may return a dict of
+device counters after (logits, caches) -- ``serving_steps`` returns the
+expert layers' -- which the engine fetches with the step's tokens and
+adds up in ``EngineStats.counters`` under ``"prefill.<name>"`` and
+``"decode.<name>"``. ``serve/cluster.py`` shards
 replicas of it across a warm ``ExecutorPool``; ``serve/spec.py`` plugs
 draft-model speculative decoding into ``step()``.
 
@@ -33,7 +37,9 @@ draft model, the draft's prefill and splice too); ``serve.decode``
 (input build and dispatch), ``serve.fetch`` (the host's wait for the
 next tokens) and ``serve.emit`` (termination bookkeeping); and, in the
 ring only, ``serve.queue`` from ``submit()`` to the start of the
-request's admission. Without a tracer each point is an ``is None`` test.
+request's admission; and a ``Tracer.counter`` event per step counter
+(the step's own count, category ``serve.prefill`` or ``serve.decode``).
+Without a tracer each point is an ``is None`` test.
 """
 from __future__ import annotations
 
@@ -109,6 +115,9 @@ class EngineStats:
     occupancy_steps: int = 0
     occupancy_tail: deque = dataclasses.field(
         default_factory=lambda: deque(maxlen=OCCUPANCY_TAIL))
+    #: step counters summed over the engine's life, "<phase>.<name>"
+    #: (phase: prefill | decode), as the steps returned them
+    counters: dict = dataclasses.field(default_factory=dict)
 
     def record_occupancy(self, n: int) -> None:
         self.occupancy_sum += int(n)
@@ -132,7 +141,8 @@ class EngineStats:
                 "truncations": self.truncations,
                 "prefill_finishes": self.prefill_finishes,
                 "spec_rounds": self.spec_rounds,
-                "mean_occupancy": self.mean_occupancy}
+                "mean_occupancy": self.mean_occupancy,
+                "counters": dict(self.counters)}
 
 
 class Engine:
@@ -232,8 +242,8 @@ class Engine:
             with maybe_span(tr, "serve.decode", SPAN_CAT):
                 tokens = jnp.asarray(self.cur_tok)[:, None]
                 pos = jnp.asarray(self.pos)
-                logits, self.caches = self.decode_fn(self.params, self.caches,
-                                                     tokens, pos)
+                logits, self.caches, *counters = self.decode_fn(
+                    self.params, self.caches, tokens, pos)
                 if self._draft_caches is not None:
                     # keep the draft cache position-consistent: the draft
                     # decodes the same token at the same position the
@@ -244,8 +254,11 @@ class Engine:
             self.stats.decode_steps += 1
             self.stats.record_occupancy(int(self.active.sum()))
             with maybe_span(tr, "serve.fetch", SPAN_CAT):
-                next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                next_tok, counters = jax.device_get(
+                    (jnp.argmax(logits, axis=-1), counters))
+                next_tok = np.asarray(next_tok, np.int32)
             with maybe_span(tr, "serve.emit", SPAN_CAT):
+                self._count("decode", counters, tr)
                 for i, req in enumerate(self.slots):
                     if req is None or not self.active[i]:
                         continue
@@ -253,6 +266,16 @@ class Engine:
                     if self._emit(i, req, int(next_tok[i])):
                         finished.append(req)
             return finished
+
+    def _count(self, phase: str, counters: list, tr) -> None:
+        """Add a step's fetched counters (none, or one dict) to the
+        stats, and emit them as counter events when tracing."""
+        total = self.stats.counters
+        for name, v in (counters[0].items() if counters else ()):
+            key = f"{phase}.{name}"
+            total[key] = total.get(key, 0) + int(v)
+            if tr is not None:
+                tr.counter(name, int(v), f"{SPAN_CAT}.{phase}")
 
     def _emit(self, slot: int, req: Request, tok: int) -> bool:
         """Append one generated token; apply the termination contract.
@@ -351,9 +374,11 @@ class Engine:
         is returned by the next ``step()``."""
         with maybe_span(tr, "serve.prefill", SPAN_CAT):
             batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
-            logits, cache1 = self.prefill_fn(self.params, batch)
+            logits, cache1, *counters = self.prefill_fn(self.params, batch)
             self.stats.prefills += 1
-            first = int(np.argmax(np.asarray(logits)[0]))
+            logits, counters = jax.device_get((logits, counters))
+            first = int(np.argmax(logits[0]))
+        self._count("prefill", counters, tr)
         req.out_tokens.append(first)
         self.stats.tokens_out += 1
         pos = len(req.prompt)

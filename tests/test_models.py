@@ -157,7 +157,8 @@ def test_donated_decode_wraps_the_ring_like_the_plain_loop(arch):
         before = host(c_don)
         given = jax.tree.leaves(c_don)
         want, c_plain = plain(params, c_plain, tok, pos)
-        got, c_don = donated(params, c_don, tok, pos)
+        # an expert model's serving decode returns its counters third
+        got, c_don, *_ = donated(params, c_don, tok, pos)
         assert all(a.is_deleted() for a in given)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         after = host(c_don)

@@ -1,5 +1,6 @@
 """Compile-only checks for one described TPU v5e chip: the Pallas kernels
-at real model widths and the full-width h2o-danube-1.8b decode step.
+at real model widths and the full-width decode steps of h2o-danube-1.8b
+and of DeepSeek-V2-Lite's one-chip expert share.
 Nothing runs; the TPU compiler refuses here what the chip would refuse
 (misaligned blocks, too much fast memory, a program that does not fit).
 
@@ -158,6 +159,53 @@ def test_danube_decode_writes_its_cache_in_place(one_chip):
                and math.prod(shape_of.get((c, args[1]), ())) >= layer]
     assert not moved, moved
     assert not written, written
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    fresh = (mem.output_size_in_bytes - mem.alias_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert fresh < layer_bytes, fresh
+
+
+def test_latent_decode_writes_its_cache_in_place(one_chip):
+    """DeepSeek-V2-Lite as the benchmark cuts it (8 of 64 experts held a
+    layer), 16 slots over an 8192-token latent cache: the decode program
+    reads each layer's latents where they lie and writes the step's rows
+    into the donated cache (no instruction outside a fusion copies or
+    slices out a layer's latents, no update writes that much), runs the
+    held experts as the TPU's grouped matmul, and allocates less than one
+    layer's latents besides the cache it aliases."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), experts_held=8)
+    slots, s_max = 16, 8192
+    model = serving_model(cfg)
+    _, decode = serving_steps(model, s_max)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    caches = on_chip(tree_shapes(model.cache_specs(slots, s_max),
+                                 dtype=cfg.dtype), one_chip)
+    compiled = decode.lower(params, caches,
+                            shaped((slots, 1), jnp.int32, one_chip),
+                            shaped((slots,), jnp.int32, one_chip)).compile()
+    layer = slots * s_max * cfg.kv_lora_rank          # one layer's latents
+    layer_bytes = layer * jnp.dtype(cfg.dtype).itemsize
+    cache_bytes = sum(c.size * c.dtype.itemsize
+                      for c in jax.tree.leaves(caches))
+    text = compiled.as_text()
+    insts, fused = _hlo_instructions(text)
+    shape_of = {(c, n): d for c, n, _, d, _ in insts}
+    # the dense first layer's stacked cache is one layer's size: its row
+    # writes (updates of the aliased cache) are checked by ``written``
+    moved = [n for c, n, op, d, _ in insts if c not in fused
+             and op not in ("parameter", "get-tuple-element", "bitcast",
+                            "dynamic-update-slice")
+             and (math.prod(d) == layer
+                  or op == "copy" and math.prod(d) >= layer)]
+    written = [n for c, n, op, _, args in insts
+               if op == "dynamic-update-slice"
+               and math.prod(shape_of.get((c, args[1]), ())) >= layer]
+    assert not moved, moved
+    assert not written, written
+    assert "ragged-dot" in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == cache_bytes
     fresh = (mem.output_size_in_bytes - mem.alias_size_in_bytes
