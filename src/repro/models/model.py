@@ -19,6 +19,7 @@ from jax.sharding import PartitionSpec as P
 from ..core.comm import cost_scope
 from ..parallel import axes as A
 from ..parallel.ops import Ops, ParallelConfig, ShardOps, remat_wrap
+from . import moe as MOE
 from . import transformer as T
 from .common import (ModelConfig, ParamSpec, gqa_layout, replicated, stacked,
                      tree_instantiate, tree_pspecs, tree_shapes)
@@ -187,11 +188,22 @@ class Model:
         p_seg = params["blocks"][seg.name]
 
         if seg.kind in ("attn_mlp", "attn_moe"):
+            stacks = self._expert_stacks(seg, p_seg, mode)
+            if stacks is not None:
+                p_seg = {**p_seg, "moe": {k: v for k, v in
+                                          p_seg["moe"].items()
+                                          if k not in stacks}}
+
             # an expert segment's per-layer aux is {"aux", "counts"}, which
             # the scans sum over the layers leaf by leaf
-            def ffn(xc, p):
+            def ffn(xc, p, layer):
                 if seg.kind == "attn_moe":
-                    xc, aux, counts = T.block_moe(ops, p, xc, cfg)
+                    if stacks is None:      # the scan sliced the experts
+                        layer = None
+                    else:
+                        p = {**p, "moe": {**p["moe"], **stacks}}
+                    xc, aux, counts = T.block_moe(ops, p, xc, cfg,
+                                                  layer=layer)
                     return xc, {"aux": aux, "counts": counts}
                 return T.block_mlp(ops, p, xc, cfg), jnp.float32(0.0)
 
@@ -205,7 +217,7 @@ class Model:
                     xc, rows = T.block_attn(ops, p, xc, cfg, rope,
                                             cache=cache, pos=pos, mode=mode,
                                             layer=layer)
-                    xc, aux = ffn(xc, p)
+                    xc, aux = ffn(xc, p, layer)
                     return xc, (rows, aux)
                 with cost_scope(seg.count):
                     x, (rows, auxs) = lax.scan(
@@ -214,12 +226,15 @@ class Model:
                         T.write_rows(cfg, cache, rows, pos))
 
             def body(xc, inp):
-                p, c = inp
+                (p, layer), c = inp
                 xc, kvc = T.block_attn(ops, p, xc, cfg, rope, cache=c,
                                        pos=pos, mode=mode, s_max=s_max)
-                xc, aux = ffn(xc, p)
+                xc, aux = ffn(xc, p, layer)
                 return xc, ((kvc if kvc is not None else {}), aux)
-            return self._scan(body, x, p_seg, cache, seg.count, mode)
+            # the layer index is scanned only where the experts need it
+            layers = None if stacks is None else jnp.arange(seg.count)
+            return self._scan(body, x, (p_seg, layers), cache, seg.count,
+                              mode)
 
         if seg.kind == "zamba_group":
             shared_p = params["blocks"]["shared"]
@@ -277,6 +292,19 @@ class Model:
             return self._scan(body, x, p_seg, cache, seg.count, mode)
 
         raise ValueError(seg.kind)
+
+    def _expert_stacks(self, seg, p_seg, mode):
+        """The held experts' stacked weights {wg, wu, wd}, (L, n, ...),
+        which an expert segment's serving scans (prefill, decode) close
+        over whole, handing each layer its index, so that the grouped
+        matmuls read them where they lie (``moe.dropless_experts``).
+        None where the scan slices them: training, a segment without
+        experts, and weights gathered from FSDP shards (a gather of the
+        whole stack in every layer)."""
+        if seg.kind != "attn_moe" or mode == "train" or \
+                (self.pcfg.fsdp and self.axes.data > 1):
+            return None
+        return {w: p_seg["moe"][w] for w in MOE.EXPERT_WEIGHTS}
 
     def _scan(self, body, x, p_seg, cache, count, mode, grouped=False):
         """Outer layer scan: body(x, (p_slice, cache_slice)) ->

@@ -14,7 +14,8 @@ Two dispatches:
 - dropless, where the layer runs without an exchange (one model shard, or
   tokens replicated over the shards, as in decode): the pairs routed to
   this shard's experts are sorted by expert and run as grouped matmuls
-  (``jax.lax.ragged_dot``) over exactly those rows. No pair is dropped.
+  (``jax.lax.ragged_dot``, or the ``stack_gmm`` kernel for a few rows in
+  serving) over exactly those rows. No pair is dropped.
 - capacity (the sharded all-to-all of training, and the gspmd path):
   sort-based placement into a capacity-bounded (held, C, d) buffer,
   exchanged with a single ``comm.alltoall`` on the model axis (the paper's
@@ -34,9 +35,13 @@ pairs routed to them; ``expert_rows``, the rows their matmuls ran
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ..parallel import axes as A
@@ -62,9 +67,12 @@ def route(ops: Ops, p, x, cfg: ModelConfig):
     return probs, topv, topi
 
 
-def moe_ffn(ops: Ops, p, x, cfg: ModelConfig, tokens_replicated: bool = False):
+def moe_ffn(ops: Ops, p, x, cfg: ModelConfig, tokens_replicated: bool = False,
+            layer=None):
     """p: {router:(d,E), wg:(held,d,f), wu:(held,d,f), wd:(held,f,d)};
-    x: (T, d).
+    x: (T, d). With ``layer`` (an int32 scalar), ``wg``/``wu``/``wd`` are
+    the whole layer stacks (L, held, ...) and this is layer ``layer`` of
+    them (see ``dropless_experts``).
 
     tokens_replicated=True (decode path): every model shard sees the same
     tokens; each shard runs only its local experts' rows, and a model-axis
@@ -77,9 +85,12 @@ def moe_ffn(ops: Ops, p, x, cfg: ModelConfig, tokens_replicated: bool = False):
     exchange = (shard and not tokens_replicated) or \
         (not isinstance(ops, ShardOps) and ops.tp > 1)
     if exchange:
+        if layer is not None:       # the capacity einsums fuse the slice
+            p = {**p, **{w: p[w][layer] for w in EXPERT_WEIGHTS}}
         out, counts = capacity_experts(ops, p, x, topv, topi, cfg)
     else:
-        out, counts = dropless_experts(ops, p, x, topv, topi, cfg, shard)
+        out, counts = dropless_experts(ops, p, x, topv, topi, cfg, shard,
+                                       layer=layer)
 
     # ---- load-balance aux (Switch): E * sum_e f_e * pbar_e ------------------
     f_e = jnp.zeros((E,), jnp.float32).at[topi.reshape(-1)].add(1.0) / (T * k)
@@ -87,20 +98,36 @@ def moe_ffn(ops: Ops, p, x, cfg: ModelConfig, tokens_replicated: bool = False):
     return out, aux, counts
 
 
-def _expert_weights(ops: Ops, p):
-    return (ops.weight(p["wg"], P(A.MODEL_AXIS, A.DATA_AXIS, None)),
-            ops.weight(p["wu"], P(A.MODEL_AXIS, A.DATA_AXIS, None)),
-            ops.weight(p["wd"], P(A.MODEL_AXIS, None, A.DATA_AXIS)))
+EXPERT_WEIGHTS = ("wg", "wu", "wd")
+
+
+def _expert_weights(ops: Ops, p, stacked: bool = False):
+    lead = (None,) if stacked else ()
+    return (ops.weight(p["wg"], P(*lead, A.MODEL_AXIS, A.DATA_AXIS, None)),
+            ops.weight(p["wu"], P(*lead, A.MODEL_AXIS, A.DATA_AXIS, None)),
+            ops.weight(p["wd"], P(*lead, A.MODEL_AXIS, None, A.DATA_AXIS)))
 
 
 def dropless_experts(ops: Ops, p, x, topv, topi, cfg: ModelConfig,
-                     shard: bool = False):
-    """This shard's held experts over exactly the rows routed to them."""
+                     shard: bool = False, layer=None):
+    """This shard's held experts over exactly the rows routed to them.
+
+    Without ``layer``, ``p["wg"]``/``p["wu"]`` are (n, d, f) and
+    ``p["wd"]`` (n, f, d): one layer's n held experts (n local to this
+    shard on a mesh), as a layer scan slices them out of the stack. With
+    ``layer`` (an int32 scalar, the serving scans' layer index) they are
+    the whole stacks (L, n, d, f) / (L, n, f, d), and the grouped matmuls
+    read them where they lie (``stack_matmul``): over the L*n experts of
+    the flattened stack, layer ``layer``'s n being experts layer*n ..
+    layer*n + n - 1. A slice of a layer fed to the grouped matmul is a
+    copy of all n experts, which a step that touches a few of them pays
+    in full. Training keeps the sliced form: a stack closed over by the
+    scan would accumulate a whole-stack gradient in every layer."""
     T, d = x.shape
     k = topi.shape[1]
     n = ops.local_experts(cfg.experts_held or cfg.n_experts)
     lo = ops.tp_index() * n if shard else 0
-    wg, wu, wd = _expert_weights(ops, p)
+    wg, wu, wd = _expert_weights(ops, p, stacked=layer is not None)
     with jax.named_scope("moe.experts"):
         local = topi.reshape(-1) - lo                          # (T*k,)
         mine = (local >= 0) & (local < n)
@@ -108,9 +135,17 @@ def dropless_experts(ops: Ops, p, x, topv, topi, cfg: ModelConfig,
         order = jnp.argsort(group)                             # stable
         sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
         rows = jnp.take(x, order // k, axis=0)                 # (T*k, d)
-        h = lax.ragged_dot(rows, wg, sizes)
-        u = lax.ragged_dot(rows, wu, sizes)
-        y = lax.ragged_dot(jax.nn.silu(h) * u, wd, sizes)      # (T*k, d)
+        if layer is None:
+            mm = functools.partial(lax.ragged_dot, group_sizes=sizes)
+        else:
+            L = wg.shape[0]
+            wg, wu, wd = (w.reshape(L * n, *w.shape[2:])
+                          for w in (wg, wu, wd))
+            mm = functools.partial(stack_matmul, sizes=sizes,
+                                   first=layer * n)
+        h = mm(rows, wg)
+        u = mm(rows, wu)
+        y = mm(jax.nn.silu(h) * u, wd)                         # (T*k, d)
     with jax.named_scope("moe.combine"):
         # back to (token, choice) order: a gather, not a scatter-add
         y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, k, d)
@@ -125,6 +160,100 @@ def dropless_experts(ops: Ops, p, x, topv, topi, cfg: ModelConfig,
         out = ops.tp_psum(out)
         counts = {name: ops.tp_psum(v) for name, v in counts.items()}
     return out, counts
+
+
+#: at most this many rows, the grouped matmul over a stack is
+#: ``stack_gmm`` on a TPU: every row times each touched expert costs less
+#: than streaming that expert's weights while rows < peak FLOP/s / HBM
+#: bytes/s (about 240 on a v5e); above it ``ragged_dot`` multiplies each
+#: group's rows alone.
+FEW_ROWS = 256
+#: VMEM for one expert's (K, tn) weight block: two of them are in flight
+_BLOCK_BYTES = 8 * 2 ** 20
+
+
+def stack_matmul(rows, w, sizes, first):
+    """Grouped matmul over a stack of experts: rows (M, K), sorted by
+    group, times w (G, K, N), where groups first .. first+n-1 of ``w``
+    take the first sum(sizes) rows, in order, and the rows past them come
+    out zero (as ``lax.ragged_dot`` gives them). The stack is read where
+    it lies: ``ragged_dot`` over all G groups, sizes zero outside the n,
+    or, for a few rows on a TPU, ``stack_gmm``, which fetches only the
+    touched experts' weights."""
+    tn = _col_block(*w.shape[1:], w.dtype.itemsize)
+    if rows.shape[0] > FEW_ROWS or tn is None:
+        return _stack_ragged_dot(rows, w, sizes, first)
+    return lax.platform_dependent(
+        rows, w, sizes, first, default=_stack_ragged_dot,
+        tpu=functools.partial(stack_gmm, block_n=tn))
+
+
+def _stack_ragged_dot(rows, w, sizes, first):
+    full = lax.dynamic_update_slice(jnp.zeros((w.shape[0],), jnp.int32),
+                                    sizes, (first,))
+    return lax.ragged_dot(rows, w, full)
+
+
+def _col_block(K: int, N: int, itemsize: int):
+    """The widest block of N columns (N itself, or a multiple of 128 that
+    divides it) whose (K, tn) weights fit ``_BLOCK_BYTES``; None if none
+    does."""
+    for tn in [N] + [t for t in range(N - N % 128, 0, -128) if N % t == 0]:
+        if K * tn * itemsize <= _BLOCK_BYTES:
+            return tn
+    return None
+
+
+def _gmm_kernel(eid_ref, size_ref, start_ref, x_ref, w_ref, o_ref):
+    e = pl.program_id(1)
+
+    @pl.when(e == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    size = size_ref[e]
+
+    @pl.when(size > 0)
+    def _():
+        y = jnp.dot(x_ref[...], w_ref[0], preferred_element_type=jnp.float32)
+        row = lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        start = start_ref[e]
+        o_ref[...] = jnp.where((row >= start) & (row < start + size),
+                               y.astype(o_ref.dtype), o_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+def stack_gmm(rows, w, sizes, first, *, block_n: int, interpret=False):
+    """``stack_matmul`` as a Pallas kernel for a few rows: the grid walks
+    (column block, group); each step multiplies all M rows by one
+    expert's (K, block_n) weights and keeps the rows of its group. An
+    empty group names the block of the last non-empty group before it
+    (the first non-empty one where none is), so the pipeline fetches no
+    weights for it: a layer's untouched experts are never read."""
+    M, K = rows.shape
+    N = w.shape[2]
+    n = sizes.shape[0]
+    sizes = sizes.astype(jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    idx = jnp.arange(n, dtype=jnp.int32)
+    last = lax.cummax(jnp.where(sizes > 0, idx, -1))
+    eid = first + jnp.where(last >= 0, last,
+                            jnp.argmax(sizes > 0).astype(jnp.int32))
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(N // block_n, n),
+        in_specs=[pl.BlockSpec((M, K), lambda j, e, eid, s, st: (0, 0)),
+                  pl.BlockSpec((1, K, block_n),
+                               lambda j, e, eid, s, st: (eid[e], 0, j))],
+        out_specs=pl.BlockSpec((M, block_n),
+                               lambda j, e, eid, s, st: (0, j)))
+    return pl.pallas_call(
+        _gmm_kernel, grid_spec=grid, name="moe_stack_gmm",
+        out_shape=jax.ShapeDtypeStruct((M, N), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=4 * _BLOCK_BYTES),
+        interpret=interpret,
+    )(eid, sizes, starts, rows, w)
 
 
 def capacity_experts(ops: Ops, p, x, topv, topi, cfg: ModelConfig):

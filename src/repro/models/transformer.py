@@ -366,9 +366,9 @@ def block_mlp(ops: Ops, p, x, cfg: ModelConfig):
     return x + ops.seq_shard(_mlp(ops, p, hf, cfg))
 
 
-def block_moe(ops: Ops, p, x, cfg: ModelConfig):
+def block_moe(ops: Ops, p, x, cfg: ModelConfig, layer=None):
     """MoE sub-block (+ optional parallel dense branch). Returns (x, aux,
-    counts) with ``moe_ffn``'s counts.
+    counts) with ``moe_ffn``'s counts; ``layer`` as ``moe_ffn`` takes it.
 
     Token layout cases (mpignite path): sequence-parallel training hands
     each model shard its own token slice (all-to-all dispatch); without SP
@@ -388,7 +388,8 @@ def block_moe(ops: Ops, p, x, cfg: ModelConfig):
     replicated = shard and not ops.pcfg.sequence_parallel and not sliced
     Bh, Sh, d = h_tok.shape
     routed, aux, counts = MOE.moe_ffn(ops, p["moe"], h_tok.reshape(-1, d),
-                                      cfg, tokens_replicated=replicated)
+                                      cfg, tokens_replicated=replicated,
+                                      layer=layer)
     routed = routed.reshape(Bh, Sh, d)
     if sliced:
         routed = ops.tp_all_gather(routed, dim=1)

@@ -161,3 +161,179 @@ def test_expert_shares_sum_to_the_uncut_layer():
         assert int(c["dropped_rows"]) == 0
     np.testing.assert_allclose(total - 7 * shared, y_full - x, atol=1e-6)
     assert routed == int(c_full["routed_rows"]) == 16 * 4
+
+
+def _stacks(cfg, L, key=KEY):
+    """L layers' held experts, stacked as the layer scan holds them."""
+    specs = MOE.moe_param_specs(cfg)
+    layers = [tree_instantiate(specs, jax.random.fold_in(key, l), 0.02,
+                               jnp.float32) for l in range(L)]
+    return {w: jnp.stack([p[w] for p in layers])
+            for w in MOE.EXPERT_WEIGHTS}, [p["router"] for p in layers]
+
+
+@pytest.mark.parametrize("mode,T,shards", [("decode", 4, 1),
+                                           ("prefill", 48, 1),
+                                           ("decode", 4, 2)])
+def test_whole_stack_equals_the_sliced_layer(mode, T, shards):
+    """Three expert layers of 4 held experts (of 8, top-2): each layer's
+    grouped matmuls over the whole (L, n, ...) stack, given the layer's
+    index, equal the same call on that layer's slice, bit for bit. At the
+    decode size some layers route no row to some held experts. With two
+    shards the model axis is named by ``vmap``: each shard holds its 2
+    of the 4 experts, the shard=True path of a decode on a mesh."""
+    L = 3
+    cfg, _, _ = setup(T=T, experts_held=4)
+    stacks, routers = _stacks(cfg, L)
+    x = jax.random.normal(jax.random.fold_in(KEY, 9), (T, cfg.d_model))
+    ops = make_ops(A.MeshAxes(1, shards, 1), PCFG)
+    n = 4 // shards
+
+    def both(local, l):
+        _, topv, topi = MOE.route(ops, {"router": routers[l]}, x, cfg)
+        whole = MOE.dropless_experts(ops, local, x, topv, topi, cfg,
+                                     shard=shards > 1, layer=jnp.int32(l))
+        sliced = MOE.dropless_experts(ops, {w: v[l] for w, v in
+                                            local.items()},
+                                      x, topv, topi, cfg, shard=shards > 1)
+        return whole, sliced
+
+    touched = []
+    for l in range(L):
+        if shards == 1:
+            (out, counts), (want, want_counts) = both(stacks, l)
+        else:
+            local = {w: jnp.stack(jnp.split(v, shards, axis=1))
+                     for w, v in stacks.items()}       # (shards, L, n, ...)
+            (out, counts), (want, want_counts) = jax.vmap(
+                lambda s, l=l: both(s, l), axis_name=A.MODEL_AXIS)(local)
+            assert local["wg"].shape[2] == n
+            out, want = out[0], want[0]
+            counts = {k: v[0] for k, v in counts.items()}
+            want_counts = {k: v[0] for k, v in want_counts.items()}
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+        assert {k: int(v) for k, v in counts.items()} == \
+            {k: int(v) for k, v in want_counts.items()}
+        assert int(counts["dropped_rows"]) == 0
+        touched.append(int(counts["experts_touched"]))
+    if mode == "decode":
+        assert min(touched) < 4, touched      # an expert left without rows
+
+
+def _moe_model(**kw):
+    """deepseek-moe-16b at smoke widths with three expert layers (after
+    its dense first layer), 4 of 8 routed experts held, float32."""
+    from repro.models.model import Model
+    cfg = dataclasses.replace(
+        get_config("deepseek-moe-16b", smoke=True), n_layers=4,
+        experts_held=4, vocab=128, dtype=jnp.float32, **kw)
+    model = Model(cfg, AXES1, PCFG)
+    return cfg, model, model.init(KEY, dtype=jnp.float32)
+
+
+def _spy(monkeypatch):
+    """Records, for each call of ``dropless_experts``, its ``layer`` and
+    the rank of the weights it was handed."""
+    calls, real = [], MOE.dropless_experts
+
+    def spy(ops, p, *a, layer=None, **k):
+        calls.append((layer is not None, p["wg"].ndim))
+        return real(ops, p, *a, layer=layer, **k)
+    monkeypatch.setattr(MOE, "dropless_experts", spy)
+    return calls
+
+
+@pytest.mark.parametrize("mode", ["prefill", "decode"])
+def test_serving_reads_the_expert_stacks_whole(monkeypatch, mode):
+    """Prefill and decode hand every expert layer the whole stack and its
+    index, and give the same logits, caches and counts, bit for bit, as
+    the same scans with the experts sliced out layer by layer."""
+    from repro.models.model import Model
+    cfg, model, params = _moe_model()
+    ops = make_ops(AXES1, PCFG)
+    B, S, s_max = 2, 8, 16
+    tokens = jax.random.randint(jax.random.fold_in(KEY, 3), (B, S), 0,
+                                cfg.vocab)
+
+    def run():
+        logits, caches, n = model.prefill(ops, params, {"tokens": tokens},
+                                          s_max=s_max, counts=True)
+        if mode == "decode":
+            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            logits, caches, n = model.decode(
+                ops, params, caches, tok, jnp.full((B,), S, jnp.int32),
+                counts=True)
+        return logits, caches, n
+
+    calls = _spy(monkeypatch)
+    got = run()
+    assert calls and all(c == (True, 4) for c in calls), calls
+    monkeypatch.setattr(Model, "_expert_stacks", lambda *a: None)
+    calls.clear()
+    want = run()
+    assert calls and all(c == (False, 3) for c in calls), calls
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_train_keeps_the_sliced_experts(monkeypatch):
+    """Training slices each layer's experts out of the stack, and its
+    loss and gradients equal those of the layers run one by one on their
+    own slices."""
+    from repro.models.layers import logits_and_xent, rmsnorm
+    cfg, model, params = _moe_model()
+    ops = make_ops(AXES1, PCFG)
+    B, S = 2, 8
+    tokens = jax.random.randint(jax.random.fold_in(KEY, 4), (B, S), 0,
+                                cfg.vocab)
+
+    def unrolled(params):
+        x, _ = model._embed_in(ops, params, {"tokens": tokens})
+        rope = model._rope(jnp.arange(S))
+        aux = 0.0
+        for seg in model.schedule:
+            for l in range(seg.count):
+                p = jax.tree.map(lambda a: a[l], params["blocks"][seg.name])
+                x, _ = T.block_attn(ops, p, x, cfg, rope, mode="train")
+                if seg.kind == "attn_moe":
+                    x, a, _ = T.block_moe(ops, p, x, cfg)
+                    aux = aux + a
+                else:
+                    x = T.block_mlp(ops, p, x, cfg)
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        nll, n_valid = logits_and_xent(
+            ops, params["head"], x[:, :-1], tokens[:, 1:],
+            jnp.ones((B, S - 1), jnp.float32), model.v_pad, cfg.vocab)
+        return nll / n_valid + cfg.router_aux_coef * aux
+
+    calls = _spy(monkeypatch)
+    loss, grads = jax.value_and_grad(
+        lambda p: model.loss(ops, p, {"tokens": tokens})[0])(params)
+    assert calls and all(c == (False, 3) for c in calls), calls
+    want, want_grads = jax.value_and_grad(unrolled)(params)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("sizes", [(5, 0, 7, 3), (0, 0, 9, 0), (0, 0, 0, 0),
+                                   (6, 6, 6, 6), (0, 4, 0, 2)])
+@pytest.mark.parametrize("block_n", [256, 128])
+def test_stack_gmm_equals_the_ragged_dot_over_the_stack(sizes, block_n):
+    """The few-rows kernel (interpret mode) over each layer of a stack of
+    3 layers x 4 experts equals ``ragged_dot`` over the whole stack with
+    sizes zero outside the layer: rows of a group times its expert, the
+    rows past the groups zero, empty groups and a layer with no rows
+    included, in one column block or two."""
+    L, n, K, N, M = 3, 4, 64, 256, 24
+    w = jax.random.normal(KEY, (L * n, K, N), jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(KEY, 1), (M, K), jnp.float32)
+    s = jnp.array(sizes, jnp.int32)
+    for layer in range(L):
+        want = MOE._stack_ragged_dot(x, w, s, layer * n)
+        got = MOE.stack_gmm(x, w, s, layer * n, block_n=block_n,
+                            interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got)[sum(sizes):], 0.0)

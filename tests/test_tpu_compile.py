@@ -172,8 +172,9 @@ def test_latent_decode_writes_its_cache_in_place(one_chip):
     reads each layer's latents where they lie and writes the step's rows
     into the donated cache (no instruction outside a fusion copies or
     slices out a layer's latents, no update writes that much), runs the
-    held experts as the TPU's grouped matmul, and allocates less than one
-    layer's latents besides the cache it aliases."""
+    held experts as the few-rows grouped-matmul kernel (``moe_stack_gmm``),
+    and allocates less than one layer's latents besides the cache it
+    aliases."""
     import dataclasses
     cfg = dataclasses.replace(get_config("deepseek-v2-lite"), experts_held=8)
     slots, s_max = 16, 8192
@@ -205,9 +206,59 @@ def test_latent_decode_writes_its_cache_in_place(one_chip):
                and math.prod(shape_of.get((c, args[1]), ())) >= layer]
     assert not moved, moved
     assert not written, written
-    assert "ragged-dot" in text
+    assert "moe_stack_gmm" in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == cache_bytes
     fresh = (mem.output_size_in_bytes - mem.alias_size_in_bytes
              + mem.temp_size_in_bytes)
     assert fresh < layer_bytes, fresh
+
+
+@pytest.mark.parametrize("arch,slots,s_max,prompt", [
+    ("deepseek-v2-lite", 16, 8192, 4096),
+    ("deepseek-moe-16b", 16, 2048, 2048)])
+def test_grouped_matmuls_read_the_expert_stacks_in_place(one_chip, arch,
+                                                         slots, s_max,
+                                                         prompt):
+    """One chip's share of an 8-way expert-parallel group (8 of 64
+    experts held a layer), in the decode program and a prefill: every
+    grouped matmul takes its weights as the stacked parameter, through
+    bitcasts only, and no instruction outside a fusion produces one
+    layer's 8 held experts (a copy out of the stack). Decode's few rows
+    run the ``moe_stack_gmm`` kernel, the prefill's many ``ragged-dot``."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config(arch), experts_held=8)
+    held, d, f = 8, cfg.d_model, cfg.moe_d_ff
+    L = cfg.n_layers - cfg.first_dense_layers
+    model = serving_model(cfg)
+    prefill, decode = serving_steps(model, s_max)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    caches = on_chip(tree_shapes(model.cache_specs(slots, s_max),
+                                 dtype=cfg.dtype), one_chip)
+    kernels = {"decode": "moe_stack_gmm", "prefill": "ragged-dot"}
+    programs = {
+        "decode": decode.lower(params, caches,
+                               shaped((slots, 1), jnp.int32, one_chip),
+                               shaped((slots,), jnp.int32, one_chip)),
+        "prefill": prefill.lower(
+            params, {"tokens": shaped((1, prompt), jnp.int32, one_chip)})}
+    for name, lowered in programs.items():
+        insts, fused = _hlo_instructions(lowered.compile().as_text())
+        by_name = {(c, n): (op, dims, args) for c, n, op, dims, args in insts}
+        layer_copies = [n for c, n, op, dims, _ in insts if c not in fused
+                        and dims in ((held, d, f), (held, f, d))]
+        assert not layer_copies, (name, layer_copies)
+        dots = [(c, n, args[-1]) for c, n, op, _, args in insts
+                if op == "custom-call"
+                and n.startswith(("ragged-dot", "moe_stack_gmm"))
+                and "metadata" not in n]
+        assert len(dots) >= 3, (name, dots)
+        assert all(n.startswith(kernels[name]) for _, n, _ in dots), \
+            (name, dots)
+        for c, n, w in dots:
+            op, dims, args = by_name[(c, w)]
+            while op == "bitcast":
+                op, dims, args = by_name[(c, args[0])]
+            assert op in ("parameter", "get-tuple-element"), (name, n, op)
+            assert dims in ((L, held, d, f), (L, held, f, d)), (name, n, dims)
