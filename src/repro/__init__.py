@@ -9,7 +9,7 @@ See README.md / DESIGN.md. Public surface:
 - ``repro.train``     -- optimizers, steps, checkpointing, fault tolerance
 - ``repro.serve``     -- continuous-batching engine
 - ``repro.kernels``   -- Pallas TPU kernels (+ jnp oracles)
-- ``repro.launch``    -- meshes, dry-run, roofline, drivers
+- ``repro.launch``    -- meshes, compile cache, serve and train drivers
 """
 
 __version__ = "1.0.0"

@@ -1,4 +1,3 @@
-from .registry import ARCHS, SHAPES, Shape, cell_plan, get_config, skip_reason
+from .registry import ARCHS, get_config
 
-__all__ = ["ARCHS", "SHAPES", "Shape", "cell_plan", "get_config",
-           "skip_reason"]
+__all__ = ["ARCHS", "get_config"]

@@ -4,7 +4,8 @@ Fine-grained MoE [arXiv:2401.06066]: 64 routed experts top-6 with
 d_ff=1408 each, plus 2 shared experts (always-on), and a dense first
 layer (d_ff=10944 per the HF checkpoint). Expert parallelism: experts
 sharded over the `model` axis, dispatched with the paper-technique
-all-to-all (PeerComm.alltoall). Full attention => long_500k skipped.
+all-to-all (PeerComm.alltoall). Full attention: the KV cache grows with
+the context.
 """
 from ..models.common import ModelConfig
 

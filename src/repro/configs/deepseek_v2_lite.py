@@ -7,9 +7,10 @@ heads are 128 (no rotary) + 64 (rotary) wide for queries and keys and
 128 for values; rotary positions are YaRN-scaled (factor 40 over 4096
 original positions). Layer 0 is dense (d_ff 10944); the other 26 have 64
 routed experts of width 1408, 6 per token with softmax scores that are
-not renormalised, and 2 shared experts. Full attention => long_500k
-skipped. DeepSeek rotates interleaved pairs; here the rotary halves are
-split (with seeded weights a fixed permutation of the rotary columns).
+not renormalised, and 2 shared experts. Full attention: the latent
+cache grows with the context. DeepSeek rotates interleaved pairs; here
+the rotary halves are split (with seeded weights a fixed permutation of
+the rotary columns).
 """
 from ..models.common import ModelConfig, YaRN
 

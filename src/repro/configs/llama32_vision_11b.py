@@ -2,10 +2,10 @@
 vocab=128256 [hf:meta-llama/Llama-3.2-11B-Vision].
 
 Backbone only per assignment: the vision tower is a stub --
-``input_specs`` provides precomputed (B, 1601, 1280) patch embeddings,
-projected by a learned (1280 -> 4096) matrix. Every 5th layer is a
-tanh-gated cross-attention block (8 groups of 4 self + 1 cross = 40).
-Full attention => long_500k skipped.
+the batch's ``image_emb`` holds precomputed (B, 1601, 1280) patch
+embeddings, projected by a learned (1280 -> 4096) matrix. Every 5th layer
+is a tanh-gated cross-attention block (8 groups of 4 self + 1 cross =
+40). Full attention: the KV cache grows with the context.
 """
 from ..models.common import ModelConfig
 

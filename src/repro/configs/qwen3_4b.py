@@ -1,6 +1,6 @@
 """qwen3-4b [dense]: 36L d_model=2560 32H (kv=8) d_ff=9728 vocab=151936
 [hf:Qwen/Qwen3-8B family]. Per-head QK-RMSNorm; explicit head_dim=128
-(> d_model/n_heads). Full attention => long_500k skipped.
+(> d_model/n_heads). Full attention: the KV cache grows with the context.
 """
 from ..models.common import ModelConfig
 

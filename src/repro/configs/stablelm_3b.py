@@ -1,7 +1,8 @@
 """stablelm-3b [dense]: 32L d_model=2560 32H (kv=32) d_ff=6912 vocab=50304.
 
 StableLM-family dense transformer [hf:stabilityai/stablelm-2-1_6b style]:
-partial rotary (rope_pct=0.25). Full attention => long_500k skipped.
+partial rotary (rope_pct=0.25). Full attention: the KV cache grows with
+the context.
 """
 from ..models.common import ModelConfig
 

@@ -16,9 +16,9 @@ every collective:
                  psum_scatter/all_to_all), overlappable by the compiler's
                  latency-hiding scheduler.
 
-Every backend logs the bytes it moves to a trace-time ``CostLog`` so that
-benchmarks and the roofline harness can cross-check analytic collective
-bytes against HLO-parsed ones.
+Every backend logs the analytic bytes and steps of each collective it
+issues to a trace-time ``CostLog``, and as a tracer instant when tracing
+is on, so a test or a trace can read what a program moves.
 
 Restrictions relative to the Spark runtime (adaptation, not omission --
 DESIGN.md section 2): routing is static (trace-time), receive-side
@@ -97,8 +97,8 @@ def _log(op: str, backend: str, nbytes: int, steps: int) -> None:
 @contextlib.contextmanager
 def _overlap_scope():
     """Everything logged inside was issued through a nonblocking wrapper:
-    mark it overlappable so the roofline can discount it against
-    compute (XLA's latency-hiding scheduler is free to move it)."""
+    mark it overlappable, since XLA's latency-hiding scheduler is free to
+    move it under compute."""
     tok = _COST_OVERLAP.set(True)
     try:
         yield

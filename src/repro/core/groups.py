@@ -144,8 +144,9 @@ def p2p_perm(groups: Groups, pairs: Sequence[tuple[int, int]],
 
 # ---------------------------------------------------------------------------
 # Byte-cost model (per device, per call) for each collective algorithm.
-# These analytic counts back the §Roofline collective term and are asserted
-# against the HLO-parsed byte counts in tests (within padding slack).
+# The message runtime's measured-vs-analytic cross-check (obs/metrics.py)
+# compares against these counts; the SPMD cost log (comm.py) records its
+# entries as ``CollectiveCost``.
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -155,8 +156,7 @@ class CollectiveCost:
     bytes_per_device: int
     steps: int
     #: logged from inside a nonblocking (i*) collective: these bytes are
-    #: candidates for communication/compute overlap, so roofline terms
-    #: may discount them against the compute term instead of serializing.
+    #: candidates for communication/compute overlap, not serial time.
     overlap: bool = False
 
 

@@ -7,10 +7,9 @@ Two halves:
   adds; no allocation on the hot path).
 - :func:`cross_check_collectives` -- compares the payload bytes a traced
   collective *actually* sent against ``groups.collective_cost``'s
-  analytic prediction. This extends the SPMD HLO byte cross-check to the
-  message runtime: the analytic model is what benchmarks and roofline
-  terms are built on, so a drift here means either the model or the
-  schedule is wrong.
+  analytic prediction. The analytic model is what the byte-model rows of
+  ``benchmarks/run.py`` are built on, so a drift here means either the
+  model or the schedule is wrong.
 
 Cross-check rules (the "documented overhead allowance" in the README):
 

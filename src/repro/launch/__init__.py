@@ -1,3 +1,3 @@
-from .mesh import make_production_mesh, make_test_mesh
+from .mesh import make_test_mesh
 
-__all__ = ["make_production_mesh", "make_test_mesh"]
+__all__ = ["make_test_mesh"]

@@ -8,8 +8,8 @@ window, then swap back to the fast backend -- demonstrating the comm-mode
 degrade <-> restore cycle end to end. Stragglers are detected with an
 EWMA step-time monitor.
 
-CPU-scale by default (smoke configs); the same driver lowers unchanged
-onto the production mesh when more devices exist.
+CPU-scale by default (smoke configs); the same driver builds a larger
+mesh (``--data``, ``--model-par``) when more devices exist.
 """
 from __future__ import annotations
 

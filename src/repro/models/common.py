@@ -4,7 +4,7 @@ One ``ModelConfig`` covers the dense / MoE / hybrid-SSM / xLSTM / VLM / audio
 families; per-arch files in ``repro/configs`` fill it in. Parameters are
 described by ``ParamSpec`` (global padded shape + PartitionSpec + init rule),
 from which each distribution path derives what it needs: GSPMD shardings,
-shard_map in_specs, local shard shapes, and dry-run ShapeDtypeStructs.
+shard_map in_specs, local shard shapes, and abstract ShapeDtypeStructs.
 
 GQA head layout under TP
 ------------------------
@@ -99,7 +99,6 @@ class ModelConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     attn_impl: str = "xla"          # xla | pallas
-    long_context_ok: bool = False   # may run the long_500k shape
     init_std: float = 0.02
 
     # ---- derived ----
@@ -225,7 +224,7 @@ def tree_pspecs(specs):
 
 
 def tree_shapes(specs, axes: A.MeshAxes | None = None, dtype=jnp.bfloat16):
-    """ShapeDtypeStructs (global shapes) for dry-run lowering; if ``axes`` is
+    """ShapeDtypeStructs (global shapes) for abstract lowering; if ``axes`` is
     given, shapes are validated to shard evenly."""
     def leaf(s: ParamSpec):
         if axes is not None:

@@ -1,9 +1,9 @@
 """Model facade: specs/init + loss / prefill / decode over the segment
 schedule, for any registry architecture, on either distribution path.
 
-Everything that must agree between the training step, the serving steps,
-the dry-run lowering and the checkpointer (shapes, PartitionSpecs, layer
-schedule, cache layout) is derived from this one class.
+Everything that must agree between the training step, the serving steps
+and the checkpointer (shapes, PartitionSpecs, layer schedule, cache
+layout) is derived from this one class.
 """
 from __future__ import annotations
 
@@ -22,7 +22,7 @@ from ..parallel.ops import Ops, ParallelConfig, ShardOps, remat_wrap
 from . import moe as MOE
 from . import transformer as T
 from .common import (ModelConfig, ParamSpec, gqa_layout, replicated, stacked,
-                     tree_instantiate, tree_pspecs, tree_shapes)
+                     tree_instantiate, tree_pspecs)
 from .layers import embed, logits_and_xent, logits_only, rmsnorm, rope_angles
 from .ssm import mamba2_cache_specs
 from .xlstm import mlstm_cache_specs, slstm_cache_specs
@@ -89,9 +89,6 @@ class Model:
         return tree_instantiate(self.specs, key, self.cfg.init_std,
                                 dtype or self.cfg.dtype)
 
-    def param_shapes(self, dtype=None):
-        return tree_shapes(self.specs, self.axes, dtype or self.cfg.dtype)
-
     # -------------------------------------------------------------- counting
     def n_params(self, active_only: bool = False) -> int:
         """Total (or per-token-active) parameter count, *excluding* head
@@ -124,11 +121,6 @@ class Model:
                 n *= shared_mult   # zamba2 shared block applied per group
             total += n
         return int(total)
-
-    def model_flops(self, n_tokens: int, train: bool = True) -> float:
-        """MODEL_FLOPS = 6*N_active*D (train) or 2*N_active*D (inference)."""
-        mult = 6.0 if train else 2.0
-        return mult * self.n_params(active_only=True) * n_tokens
 
     # --------------------------------------------------------------- forward
     def _embed_in(self, ops: Ops, params, batch):

@@ -2,7 +2,7 @@
 
 An architecture is compiled into a list of ``Segment``s; each segment is a
 homogeneous run of layers whose stacked parameters are swept with
-``lax.scan`` (keeping HLO size and 512-way SPMD compile time bounded).
+``lax.scan`` (keeping HLO size and compile time bounded in depth).
 Heterogeneous interleavings (zamba2's shared attention every 6 Mamba
 layers, llama-vision's cross-attention every 5th layer, xLSTM's sLSTM
 positions) become *grouped* segments: outer scan over groups, inner scan

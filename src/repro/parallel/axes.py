@@ -8,8 +8,8 @@ Axis names are fixed across the framework:
 - ``model`` : tensor/expert parallelism (Megatron-style TP; MoE experts and
               the vocab dimension also live here).
 
-Hardware-alignment padding (recorded in DESIGN.md; the MODEL_FLOPS/HLO_FLOPs
-ratio in the roofline table surfaces the waste these introduce):
+Hardware-alignment padding (recorded in DESIGN.md; ``Model.n_params``
+leaves the waste these introduce out of the useful parameter count):
 
 - attention heads are padded up to a multiple of the TP degree
   (e.g. arctic-480b 56 -> 64 query heads on a 16-way model axis);

@@ -22,7 +22,6 @@ fields.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -38,17 +37,11 @@ class ParallelConfig:
     """User-facing knobs for the distribution layer."""
     path: str = "mpignite"            # "mpignite" | "gspmd"
     backend: str = "native"           # PeerComm backend (mpignite path)
-    pod_backend: str | None = None    # override for cross-pod traffic
     sequence_parallel: bool = True    # keep activations seq-sharded between blocks
     fsdp: bool = True                 # ZeRO-3 parameter sharding over `data`
     remat: str = "block"              # "none" | "block" | "full"
     grad_compression: str = "none"    # "none" | "int8" (cross-pod allreduce)
-    weight_gather_quant: str = "none" # "none" | "int8" (ZeRO++-style qwZ:
-                                      # FSDP all-gathers move int8 + scales)
     microbatches: int = 1             # grad-accumulation chunks per step
-    microbatch_dtype: str = "float32" # accumulator dtype ("bfloat16" halves
-                                      # the grad buffer; lean-memory mode)
-    scan_layers: bool = True          # lax.scan over stacked layer params
 
     def replace(self, **kw) -> "ParallelConfig":
         return dataclasses.replace(self, **kw)
@@ -134,8 +127,7 @@ class ShardOps(Ops):
         be = pcfg.backend
         self.comm_model = PeerComm.world(A.MODEL_AXIS, axes.model, backend=be)
         self.comm_data = PeerComm.world(A.DATA_AXIS, axes.data, backend=be)
-        self.comm_pod = (PeerComm.world(A.POD_AXIS, axes.pod,
-                                        backend=pcfg.pod_backend or be)
+        self.comm_pod = (PeerComm.world(A.POD_AXIS, axes.pod, backend=be)
                          if axes.pod > 1 else None)
 
     # ---- static sizes ----------------------------------------------------
@@ -153,46 +145,8 @@ class ShardOps(Ops):
         for dim, entry in enumerate(entries):
             names = entry if isinstance(entry, tuple) else (entry,)
             if A.DATA_AXIS in names and self.axes.data > 1:
-                if self.pcfg.weight_gather_quant == "int8" and \
-                        jnp.issubdtype(w.dtype, jnp.floating):
-                    w = self._quantized_gather(w, dim)
-                else:
-                    w = self.comm_data.allgather(w, axis=dim, tiled=True)
+                w = self.comm_data.allgather(w, axis=dim, tiled=True)
         return w
-
-    def _quantized_gather(self, w, dim: int):
-        """ZeRO++-style quantized weight gather (qwZ, arXiv:2306.10209):
-        the forward FSDP all-gather moves int8 payloads + one bf16 scale
-        per sharded row -- half the bf16 wire bytes -- while the backward
-        pass reduce-scatters cotangents exactly (the transpose of a full-
-        precision gather), so only forward weights carry the ~0.4% RMS
-        quantization error."""
-        comm = self.comm_data
-        dt = w.dtype
-
-        @jax.custom_vjp
-        def qgather(w):
-            return _fwd(w)[0]
-
-        def _fwd(w):
-            shard = w.shape[dim]
-            scale = jnp.max(jnp.abs(w), axis=dim, keepdims=True) / 127.0 \
-                + 1e-12
-            q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale),
-                         -127, 127).astype(jnp.int8)
-            qg = comm.allgather(q, axis=dim, tiled=True)
-            sg = comm.allgather(scale.astype(jnp.bfloat16), axis=dim,
-                                tiled=True)          # one scale per shard
-            sg = jnp.repeat(sg, shard, axis=dim)     # broadcast per block
-            out = (qg.astype(jnp.float32) * sg.astype(jnp.float32)
-                   ).astype(dt)
-            return out, None
-
-        def _bwd(_, g):
-            return (comm.reducescatter(g, axis=dim),)
-
-        qgather.defvjp(_fwd, _bwd)
-        return qgather(w)
 
     # ---- activation collectives -------------------------------------------
     def tp_psum(self, x):

@@ -2,23 +2,24 @@
 
 A fixed pool of ``max_slots`` sequence slots shares one decode step
 (compiled once for the full batch); requests are admitted from a FIFO
-queue as slots free up, prefilled individually (chunked prefill for long
-prompts), and decoded together every engine step. Finished sequences
-(EOS or budget) release their slot immediately -- the decode batch is
-always full-width with a per-slot active mask, which is the standard
-continuous-batching trick to keep the compiled shape static.
+queue as slots free up, prefilled individually, and decoded together
+every engine step. Finished sequences (EOS or budget) release their
+slot immediately -- the decode batch is always full-width with a
+per-slot active mask, which is the standard continuous-batching trick
+to keep the compiled shape static.
 
 The engine is deliberately runtime-agnostic: ``prefill_fn``/``decode_fn``
-are the compiled steps from train/step.py, so the same engine drives a
-1-device CPU smoke test and a 512-chip mesh. ``decode_fn`` may donate
-the cache it is given (``launch.serve.serving_steps`` does): the engine
-keeps only the cache it returns. Either step may return a dict of
-device counters after (logits, caches) -- ``serving_steps`` returns the
-expert layers' -- which the engine fetches with the step's tokens and
-adds up in ``EngineStats.counters`` under ``"prefill.<name>"`` and
-``"decode.<name>"``. ``serve/cluster.py`` shards
-replicas of it across a warm ``ExecutorPool``; ``serve/spec.py`` plugs
-draft-model speculative decoding into ``step()``.
+are compiled steps handed in by the caller, and the engine itself holds
+no device or mesh layout. The served programs' steps come from
+``launch.serve.serving_steps``. ``decode_fn`` may donate the cache it is
+given (``serving_steps``' does): the engine keeps only the cache it
+returns. Either step may return a dict of device counters after
+(logits, caches) -- ``serving_steps`` returns the expert layers' --
+which the engine fetches with the step's tokens and adds up in
+``EngineStats.counters`` under ``"prefill.<name>"`` and
+``"decode.<name>"``. ``serve/cluster.py`` shards replicas of it across
+a warm ``ExecutorPool``; ``serve/spec.py`` plugs draft-model speculative
+decoding into ``step()``.
 
 Termination contract: a request finishes when its token hits ``eos_id``,
 its ``max_new_tokens`` budget is spent, or its position runs out of

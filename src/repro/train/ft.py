@@ -24,7 +24,6 @@ deterministically with a fake clock).
 from __future__ import annotations
 
 import dataclasses
-import time
 
 
 class SimulatedFailure(RuntimeError):

@@ -85,17 +85,14 @@ def make_train_step(model: Model, opt: Optimizer, mesh: Mesh,
                 lambda x: x.reshape((m, x.shape[0] // m) + x.shape[1:]),
                 batch)
 
-            acc_dt = jnp.dtype(pcfg.microbatch_dtype)
-
             def acc_step(acc, b):
                 (l, met), g = grad_of(b)
                 acc = jax.tree.map(
-                    lambda a, gi: a + (gi.astype(jnp.float32) / m
-                                       ).astype(acc_dt), acc, g)
+                    lambda a, gi: a + gi.astype(jnp.float32) / m, acc, g)
                 return acc, (l, met)
 
             acc0 = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, acc_dt), params)
+                lambda p: jnp.zeros(p.shape, jnp.float32), params)
             from ..core.comm import cost_scope
             with cost_scope(m):
                 grads, (losses, mets) = jax.lax.scan(acc_step, acc0, mb)

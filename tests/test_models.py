@@ -1,7 +1,11 @@
 """Per-architecture smoke + decode-parity tests (single device, reduced
-configs -- the full configs are exercised only via the dry-run)."""
+configs; the published-width serving programs are compiled for a v5e in
+tests/test_tpu_compile.py)."""
 import dataclasses
+import os
 import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -241,3 +245,21 @@ def test_n_params_counts():
     assert 4.3e11 < n < 5.3e11, n      # ~480B total
     na = model.n_params(active_only=True)
     assert na < 0.1 * n                # top-2 of 128 experts + dense
+
+
+def test_launch_and_configs_import_sets_no_xla_flags():
+    """Importing every module of ``repro.launch`` and ``repro.configs``
+    sets no ``XLA_FLAGS`` and starts no backend, so a process may import
+    them before it forces a host device count or picks a device."""
+    code = ("import importlib, os, pkgutil\n"
+            "import repro.configs, repro.launch\n"
+            "for pkg in (repro.configs, repro.launch):\n"
+            "    for m in pkgutil.iter_modules(pkg.__path__,\n"
+            "                                  pkg.__name__ + '.'):\n"
+            "        importlib.import_module(m.name)\n"
+            "assert 'XLA_FLAGS' not in os.environ, os.environ['XLA_FLAGS']\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env={**env, "JAX_PLATFORMS": "cpu"})

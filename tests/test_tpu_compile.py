@@ -1,6 +1,7 @@
 """Compile-only checks for one described TPU v5e chip: the Pallas kernels
-at real model widths and the full-width decode steps of h2o-danube-1.8b
-and of DeepSeek-V2-Lite's one-chip expert share.
+at real model widths, the full-width prefill and decode steps of
+h2o-danube-1.8b and the decode of DeepSeek-V2-Lite's one-chip expert
+share.
 Nothing runs; the TPU compiler refuses here what the chip would refuse
 (misaligned blocks, too much fast memory, a program that does not fit).
 
@@ -102,6 +103,24 @@ def test_danube_decode_step_compiles_at_full_width(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16e9, used           # one v5e chip holds 16 GB
+
+
+def test_danube_prefill_compiles_at_full_width(one_chip):
+    """The prefill program ``launch.serve`` builds for a 4096-token cache,
+    on the chat traffic's longest prompt (2048 tokens), all 24 layers in
+    bfloat16, fits one chip."""
+    cfg = get_config("h2o-danube-1.8b")
+    s_max, prompt = 4096, 2048
+    model = serving_model(cfg)
+    prefill, _ = serving_steps(model, s_max)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    compiled = prefill.lower(
+        params, {"tokens": shaped((1, prompt), jnp.int32, one_chip)}).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16 * 2 ** 30, used   # one v5e chip holds 16 GiB
 
 
 def _hlo_instructions(text):

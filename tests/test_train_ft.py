@@ -7,8 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.data.pipeline import (MemmapTokens, Prefetcher, SyntheticTokens,
-                                 make_batch)
+from repro.data.pipeline import MemmapTokens, Prefetcher, SyntheticTokens
 from repro.train import checkpoint as CKPT
 from repro.train import ft
 from repro.train.optim import OptConfig, Optimizer, lr_at
